@@ -10,27 +10,24 @@ over the post-SPMD module (xla's cost_analysis undercounts scan bodies).
 Link-byte model: all-reduce costs 2x its payload (reduce-scatter +
 all-gather halves of a ring), the others 1x.
 
-The hardware peaks are parameters, not constants: :class:`HardwarePeaks`
-defaults to a TPU v5e-class chip (197 TFLOP/s bf16, 819 GB/s HBM,
-50 GB/s per ICI link) and can be overridden per run via the
-``REPRO_PEAK_FLOPS`` / ``REPRO_PEAK_HBM_BW`` / ``REPRO_PEAK_LINK_BW`` /
-``REPRO_PEAK_CHIPS`` environment knobs or the CLI flags below — the same
-analysis answers "how far off the roof are we" on any accelerator.
+The hardware peaks come from :data:`PEAKS`, a table keyed by the
+``device_kind`` jax reports, each row with its published source.  A device
+kind missing from the table is an error (:func:`peaks_for`), never a
+default: a roofline drawn against the wrong chip's peaks is a wrong number.
 
 :func:`achieved_vs_peak` is the live half (ROADMAP Pallas item):
 ``benchmarks/run.py`` registers it as the ``achieved_vs_peak`` obs
 estimator, so the PGM kernel bench blocks (``--latent``, ``--structure``)
 stamp measured-throughput-vs-roof fractions (and the compute/memory
 bound classification) next to each row, from the analytical FLOP/byte
-counts of the very program they timed.
+counts of the very program they timed — on a chip in :data:`PEAKS` only.
 
 MODEL_FLOPS = 6*N*D (train) or 2*N*D (inference) with N = active params;
 the ratio MODEL_FLOPS / HLO_FLOPs exposes remat/attention/padding overhead.
 
 Usage: PYTHONPATH=src python -m benchmarks.roofline \
            [--dryrun results/dryrun] [--hlo results/hlo] [--mesh 16x16] \
-           [--peak-flops 1.97e14] [--hbm-bw 8.19e11] [--link-bw 5e10] \
-           [--chips 256]
+           [--device-kind "TPU v5 lite"] [--chips 256]
 Writes results/roofline.csv and results/roofline.md.
 """
 
@@ -49,33 +46,30 @@ from typing import Optional
 class HardwarePeaks:
     """Peak rates of the accelerator the roofline is drawn against."""
 
-    flops: float = 197e12       # bf16 MXU peak, FLOP/s per chip
-    hbm_bw: float = 819e9       # HBM bandwidth, B/s per chip
-    link_bw: float = 50e9       # ICI per-link bandwidth, B/s
-    chips: int = 256            # pod size for per-device splits
-
-    @classmethod
-    def from_env(cls, **overrides: float) -> "HardwarePeaks":
-        """Defaults <- REPRO_PEAK_* env vars <- explicit overrides."""
-        vals = {}
-        for field, env in (("flops", "REPRO_PEAK_FLOPS"),
-                           ("hbm_bw", "REPRO_PEAK_HBM_BW"),
-                           ("link_bw", "REPRO_PEAK_LINK_BW"),
-                           ("chips", "REPRO_PEAK_CHIPS")):
-            if env in os.environ:
-                cast = int if field == "chips" else float
-                vals[field] = cast(float(os.environ[env]))
-        vals.update({k: v for k, v in overrides.items() if v is not None})
-        return cls(**vals)
+    flops: float                # bf16 MXU peak, FLOP/s per chip
+    hbm_bw: float               # HBM bandwidth, B/s per chip
+    link_bw: float              # ICI per-link bandwidth, B/s
+    source: str
 
 
-DEFAULT_PEAKS = HardwarePeaks()
+# Keyed by ``jax.devices()[i].device_kind``.
+PEAKS = {
+    "TPU v5 lite": HardwarePeaks(
+        flops=197e12, hbm_bw=819e9,
+        # 1,600 Gbit/s of chip-to-chip interconnect over 4 ICI links
+        link_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e' system architecture"),
+}
 
-# Back-compat aliases for the former module constants.
-PEAK_FLOPS = DEFAULT_PEAKS.flops
-HBM_BW = DEFAULT_PEAKS.hbm_bw
-LINK_BW = DEFAULT_PEAKS.link_bw
-CHIPS = DEFAULT_PEAKS.chips
+
+def peaks_for(device_kind: str) -> HardwarePeaks:
+    """The published peaks of ``device_kind``; raises on a kind not in
+    :data:`PEAKS`."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 
 def achieved_vs_peak(*, seconds: float, flops: Optional[float] = None,
@@ -84,29 +78,33 @@ def achieved_vs_peak(*, seconds: float, flops: Optional[float] = None,
     """Score a measured region against the hardware roof.
 
     ``flops`` / ``hbm_bytes`` are the work done in ``seconds`` (per
-    device); returns achieved FLOP/s and B/s, their fractions of peak,
-    and which roof the region sits under (``bound``: the resource whose
-    peak-fraction is higher is the one limiting further speedup).
+    device); ``peaks`` default to those of the device jax runs on (an
+    unknown device kind raises).  Returns achieved FLOP/s and B/s, their
+    fractions of peak, and which roof the region sits under (``bound``:
+    the resource whose peak-fraction is higher is the one limiting further
+    speedup).
     Registered as the ``achieved_vs_peak`` obs estimator by
     ``benchmarks/run.py``.
     """
-    p = peaks if peaks is not None else HardwarePeaks.from_env()
+    if peaks is None:
+        import jax
+
+        peaks = peaks_for(jax.devices()[0].device_kind)
     out: dict = {"seconds": seconds,
-                 "peak_flops": p.flops, "peak_hbm_bw": p.hbm_bw}
+                 "peak_flops": peaks.flops, "peak_hbm_bw": peaks.hbm_bw}
     frac_f = frac_b = None
     if flops is not None and seconds > 0:
         out["achieved_flops_per_s"] = flops / seconds
-        frac_f = out["frac_peak_flops"] = flops / seconds / p.flops
+        frac_f = out["frac_peak_flops"] = flops / seconds / peaks.flops
     if hbm_bytes is not None and seconds > 0:
         out["achieved_bytes_per_s"] = hbm_bytes / seconds
-        frac_b = out["frac_peak_hbm_bw"] = hbm_bytes / seconds / p.hbm_bw
+        frac_b = out["frac_peak_hbm_bw"] = hbm_bytes / seconds / peaks.hbm_bw
     if frac_f is not None and frac_b is not None:
         out["bound"] = "compute" if frac_f >= frac_b else "memory"
     return out
 
 
-def model_flops_per_device(rec: dict,
-                           peaks: HardwarePeaks = DEFAULT_PEAKS) -> float:
+def model_flops_per_device(rec: dict, chips: int) -> float:
     """6*N_active*D (train) / 2*N_active*D (inference), per chip."""
     from repro.configs.base import INPUT_SHAPES
 
@@ -120,11 +118,11 @@ def model_flops_per_device(rec: dict,
         total = 2.0 * n * tokens
     else:  # decode: ONE token per stream
         total = 2.0 * n * shape.global_batch
-    return total / peaks.chips
+    return total / chips
 
 
-def analyze_record(rec: dict, hlo_dir: str,
-                   peaks: HardwarePeaks = DEFAULT_PEAKS) -> dict:
+def analyze_record(rec: dict, hlo_dir: str, peaks: HardwarePeaks,
+                   chips: int) -> dict:
     from benchmarks.hlo_analysis import analyze
 
     tag = f"{rec['arch']}__{rec['shape']}__{rec['mesh']}"
@@ -144,7 +142,7 @@ def analyze_record(rec: dict, hlo_dir: str,
     terms = {"compute": compute_s, "memory": memory_s_min,
              "collective": coll_s}
     dominant = max(terms, key=terms.get)
-    mf = model_flops_per_device(rec, peaks)
+    mf = model_flops_per_device(rec, chips)
     rec = dict(rec)
     rec.update({
         "hlo_flops": h["flops"], "hlo_bytes": h["hbm_bytes"],
@@ -186,18 +184,13 @@ def main(argv=None) -> int:
     ap.add_argument("--hlo", default="results/hlo")
     ap.add_argument("--mesh", default="16x16")
     ap.add_argument("--out", default="results/roofline")
-    ap.add_argument("--peak-flops", type=float, default=None,
-                    help="peak FLOP/s per chip (default: v5e-class 197e12; "
-                         "env REPRO_PEAK_FLOPS)")
-    ap.add_argument("--hbm-bw", type=float, default=None,
-                    help="HBM B/s per chip (default 819e9; REPRO_PEAK_HBM_BW)")
-    ap.add_argument("--link-bw", type=float, default=None,
-                    help="ICI link B/s (default 50e9; REPRO_PEAK_LINK_BW)")
-    ap.add_argument("--chips", type=int, default=None,
-                    help="pod size (default 256; REPRO_PEAK_CHIPS)")
+    ap.add_argument("--device-kind", default="TPU v5 lite",
+                    help="device_kind whose peaks (PEAKS) draw the roof; "
+                         "the dry-run meshes are v5e pods")
+    ap.add_argument("--chips", type=int, default=256,
+                    help="pod size for the per-device model-FLOP split")
     args = ap.parse_args(argv)
-    peaks = HardwarePeaks.from_env(flops=args.peak_flops, hbm_bw=args.hbm_bw,
-                                   link_bw=args.link_bw, chips=args.chips)
+    peaks = peaks_for(args.device_kind)
 
     recs = []
     for path in sorted(glob.glob(os.path.join(args.dryrun, "*.json"))):
@@ -209,7 +202,7 @@ def main(argv=None) -> int:
             recs.append(rec)
             continue
         try:
-            recs.append(analyze_record(rec, args.hlo, peaks))
+            recs.append(analyze_record(rec, args.hlo, peaks, args.chips))
         except FileNotFoundError:
             rec["note"] = "no HLO dump"
             recs.append(rec)

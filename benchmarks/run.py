@@ -189,7 +189,7 @@ def register_estimators() -> None:
     * ``"achieved_vs_peak"`` — ``roofline.achieved_vs_peak``: measured
       seconds + analytical FLOPs/bytes -> fraction-of-roof and
       compute/memory bound classification (the live half of the ROADMAP
-      roofline gate; peaks tunable via ``REPRO_PEAK_*``).
+      roofline gate; peaks from ``roofline.PEAKS`` by device kind).
 
     When obs is enabled every estimate is also a ``bench_estimate``
     JSONL event."""
@@ -222,12 +222,16 @@ def _achieved_vs_peak_row(analytical, us_per_call: float):
     """achieved-vs-peak stamp for one bench row: analytical FLOP/byte
     counts + the measured per-call time -> fraction-of-roof dict (None
     when the cost model produced nothing to score)."""
+    import jax
+
     from repro import obs
 
     if not analytical or not analytical.get("flops"):
         return None
     if not obs.registered("achieved_vs_peak"):
         return None
+    if jax.devices()[0].platform != "tpu":
+        return None     # a host-CPU time is no device roofline share
     return obs.estimate("achieved_vs_peak", seconds=us_per_call / 1e6,
                         flops=analytical["flops"],
                         hbm_bytes=analytical.get("hbm_bytes_min"))
@@ -236,32 +240,21 @@ def _achieved_vs_peak_row(analytical, us_per_call: float):
 def _program_analysis(lowered):
     """(peak_mem_bytes, analytical) of a lowered program — ONE compile
     shared by the peak-memory proxy and the registered ``hlo_cost``
-    analytical FLOP/byte model.  Either half degrades to None if the
-    backend exposes no memory analysis / HLO text."""
+    analytical FLOP/byte model.  A program that does not compile raises."""
     from repro import obs
 
-    try:
-        compiled = lowered.compile()
-    except Exception:
-        return None, None
-    try:
-        ma = compiled.memory_analysis()
-        peak = float(ma.temp_size_in_bytes + ma.argument_size_in_bytes
-                     + ma.output_size_in_bytes)
-    except Exception:
-        peak = None
+    compiled = lowered.compile()
+    ma = compiled.memory_analysis()
+    peak = float(ma.temp_size_in_bytes + ma.argument_size_in_bytes
+                 + ma.output_size_in_bytes)
     analytical = None
-    try:
-        if obs.registered("hlo_cost"):
-            analytical = obs.estimate("hlo_cost", compiled.as_text())
-    except Exception:
-        analytical = None
+    if obs.registered("hlo_cost"):
+        analytical = obs.estimate("hlo_cost", compiled.as_text())
     return peak, analytical
 
 
 def _peak_mem_proxy(lowered):
-    """Compiled-program peak-memory proxy in bytes (None if the backend
-    exposes no memory analysis — e.g. some CPU jaxlibs)."""
+    """Compiled-program peak-memory proxy in bytes."""
     return _program_analysis(lowered)[0]
 
 
@@ -434,7 +427,7 @@ def bench_dvmp_json(n: int = 50_000, sweeps: int = 5, k: int = 3, f: int = 8,
     import jax
 
     from repro.core import dvmp, vmp
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.core.dag import PlateSpec
     from repro.data.synthetic import gmm_stream
 
@@ -1058,7 +1051,7 @@ def bench_serve_json(duration: float = 3.0, loads: tuple = (200.0, 800.0),
     import jax
 
     from repro import obs
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.data.synthetic import gmm_stream
     from repro.pgm_models import GaussianMixture
     from repro.serve.queue import AsyncPGMServer
@@ -1731,6 +1724,9 @@ def main(argv=None) -> None:
                     help="capture a jax.profiler trace of the benchmark "
                          "run into DIR (open with TensorBoard/Perfetto)")
     args = ap.parse_args(argv)
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
 
     if ((args.dvmp or args.latent or args.structure or args.temporal
          or args.serve or args.resilience) and not args.json):

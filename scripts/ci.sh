@@ -96,7 +96,7 @@ python -m pytest -x -q "$@"
 if [ "$#" -gt 0 ]; then
     python -m pytest -x -q tests/test_kernels.py
 fi
-DEFAULT_INTERPRET="$(python -c 'from repro.kernels import ops; print(int(ops.INTERPRET))')"
+DEFAULT_INTERPRET="$(python -c 'from repro.kernels import clg_stats; print(int(clg_stats._resolve_interpret(None)))')"
 if [ "$DEFAULT_INTERPRET" = "0" ]; then
     echo "ci: kernel parity leg (default policy compiles — forcing interpret)"
     REPRO_PALLAS_INTERPRET=1 python -m pytest -x -q tests/test_kernels.py

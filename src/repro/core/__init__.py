@@ -10,7 +10,6 @@ Submodules:
   importance_sampling  parallel likelihood weighting for CLG networks
   factored_frontier    dynamic-BN filtering/smoothing (lax.scan)
   map_inference        scalable MAP / abductive inference
-  compat               jax version shims (shard_map, make_mesh)
 
 Exact inference (junction tree) lives in the sibling package
 ``repro.infer_exact`` — the paper's HUGIN link, replaced natively.

@@ -31,7 +31,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from repro.core.compat import shard_map
+from jax import shard_map
 
 from repro.core import vmp as V
 from repro.core.vmp import CompiledPlate, PlateParams, PlateStats, VMPState
@@ -148,7 +148,10 @@ def dvmp_fit(
     sweeps-to-convergence.
     """
     if mask is None:
-        mask = jnp.ones(xc.shape[0], xc.dtype)
+        # laid out like the data: a default-device mask would pull every
+        # shard's slice through one chip
+        mask = jnp.ones(xc.shape[0], xc.dtype,
+                        device=NamedSharding(mesh, P(tuple(data_axes))))
     prog = _fit_program(cp, mesh, tuple(data_axes), max_sweeps, tol,
                         backend, chunk, with_metrics)
     return prog(prior, init, xc, xd, mask)

@@ -18,12 +18,13 @@ Everything is pure-functional jnp; no Python objects cross jit boundaries.
 
 from __future__ import annotations
 
+import math
 from typing import NamedTuple, Optional
 
 import jax.numpy as jnp
 from jax.scipy.special import digamma, gammaln
 
-LOG2PI = float(jnp.log(2.0 * jnp.pi))
+LOG2PI = math.log(2.0 * math.pi)
 
 # ---------------------------------------------------------------------------
 # Dirichlet / Categorical

@@ -17,7 +17,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.core.compat import shard_map
+from jax import shard_map
 
 from repro.core.dag import BayesianNetwork, Variable
 

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import jax.scipy.special as jsp
 
 # Flip on to route marginalize/absorb through the Pallas kernels
-# (interpret-mode on CPU; compiled on TPU via REPRO_PALLAS_COMPILE=1).
+# (compiled on a TPU, interpret mode elsewhere: kernels.clg_stats policy).
 USE_PALLAS = os.environ.get("REPRO_EXACT_PALLAS", "0") == "1"
 
 NEG_INF = float("-inf")

@@ -12,29 +12,40 @@ and, for every discrete leaf and component, the one-hot count reduction
 
     disc[f,k,c] = sum_n r[n,k] [x[n,f] == c]         [C]
 
-TPU mapping: grid (F, K, n_instance_blocks) with the instance dim minor
-(sequential), accumulating the [D, D] tile in VMEM scratch; the inner
-products are [D, bn] x [bn, D] MXU matmuls.  The per-shard result is the
-psum payload of dvmp (one message pytree per sweep).
+TPU mapping: every reduction is one weighted Gram matrix
+``G = A B^T`` over the instance axis.  Inputs are laid out instance-minor
+(``[rows, N]``: the instance axis is the 128-lane axis, the few features /
+components / design dims are sublane rows, each block covers all of them),
+so every BlockSpec is ``(all rows, block)`` and satisfies the TPU's
+(8, 128) tiling rule for any feature count.  The grid is the instance
+blocks alone (sequential); per block the kernel writes the rows of ``A``
+(responsibility-weighted) and ``B`` (design products) into VMEM scratch
+and accumulates ``A B^T`` — one MXU matmul contracting the instance block —
+into the resident output.  The wrappers slice the few-hundred-element Gram
+matrix into the suff-stat pytree.  The per-shard result is the psum
+payload of dvmp (one message pytree per sweep).
 
-``interpret=None`` (the default) compiles the kernel natively when the
-default jax backend is a TPU (or ``REPRO_PALLAS_COMPILE=1`` forces it) and
-falls back to interpret mode on CPU — same policy as the factor-algebra
-kernels behind ``repro.kernels.ops.INTERPRET``.
+``interpret=None`` (the default) resolves at call time through
+:func:`_resolve_interpret`, the one compile/interpret policy every kernel
+of this package follows.
 
-Oracles: ``repro.kernels.ref.{clg_suffstats_ref,clg_disc_counts_ref}``.
+Oracles: ``repro.kernels.ref.{clg_suffstats_ref,clg_suffstats_latent_ref,
+clg_disc_counts_ref}``.
 """
 
 from __future__ import annotations
 
 import functools
 import os
-from typing import Optional, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+Row = jnp.ndarray                    # one [1, block] float32 row
+RowFn = Callable[[Callable[[int, int], Row]], List[Row]]
 
 
 def _resolve_interpret(interpret: Optional[bool]) -> bool:
@@ -43,7 +54,9 @@ def _resolve_interpret(interpret: Optional[bool]) -> bool:
     ``REPRO_PALLAS_INTERPRET=1`` forces interpret mode everywhere (wins
     over COMPILE): the CI parity leg runs the kernel suite once under each
     policy so the TPU-compiled path cannot silently diverge from the
-    interpret semantics the CPU container tests."""
+    interpret semantics the CPU container tests.  Asked at call time, never
+    at import: asking initializes the backend, which takes hold of the
+    chip."""
     if interpret is not None:
         return interpret
     if os.environ.get("REPRO_PALLAS_INTERPRET", "0") == "1":
@@ -53,129 +66,134 @@ def _resolve_interpret(interpret: Optional[bool]) -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _kernel(d_ref, y_ref, r_ref, sxx_ref, sxy_ref, syy_ref,
-            sxx_scr, sxy_scr, syy_scr, *, nb: int):
-    bi = pl.program_id(2)
+def _round8(n: int) -> int:
+    return -(-n // 8) * 8
 
-    @pl.when(bi == 0)
+
+def _gram_kernel(*refs, n_in: int, lhs: RowFn, rhs: RowFn):
+    in_refs, out_ref = refs[:n_in], refs[n_in]
+    a_scr, b_scr = refs[n_in + 1:]
+
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        sxx_scr[...] = jnp.zeros_like(sxx_scr)
-        sxy_scr[...] = jnp.zeros_like(sxy_scr)
-        syy_scr[...] = jnp.zeros_like(syy_scr)
+        out_ref[...] = jnp.zeros_like(out_ref)
+        # rows past the recipe stay zero for the whole grid
+        a_scr[...] = jnp.zeros_like(a_scr)
+        b_scr[...] = jnp.zeros_like(b_scr)
 
-    d = d_ref[0].astype(jnp.float32)          # [bn, D]
-    y = y_ref[0].astype(jnp.float32)          # [bn]
-    r = r_ref[0].astype(jnp.float32)          # [bn]  (component k's column)
+    def row(i: int, j: int) -> Row:
+        return in_refs[i][pl.ds(j, 1), :].astype(jnp.float32)
 
-    dw = d * r[:, None]                       # [bn, D]
-    sxx_scr[...] += jax.lax.dot_general(
-        dw, d, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)   # [D, D]
-    sxy_scr[...] += (dw * y[:, None]).sum(0)  # [D]
-    syy_scr[0] += (r * y * y).sum()
+    for i, v in enumerate(lhs(row)):
+        a_scr[pl.ds(i, 1), :] = v
+    for j, v in enumerate(rhs(row)):
+        b_scr[pl.ds(j, 1), :] = v
+    out_ref[...] += jax.lax.dot_general(
+        a_scr[...], b_scr[...], (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
-    @pl.when(bi == nb - 1)
-    def _final():
-        sxx_ref[0, 0] = sxx_scr[...]
-        sxy_ref[0, 0] = sxy_scr[...]
-        syy_ref[0, 0] = syy_scr[0]
+
+def _gram(inputs: Sequence[jnp.ndarray], pad_values: Sequence[float],
+          lhs: RowFn, n_lhs: int, rhs: RowFn, n_rhs: int, *, block: int,
+          interpret: Optional[bool]) -> jnp.ndarray:
+    """``G[i, j] = sum_n lhs_i[n] * rhs_j[n]`` -> ``[n_lhs, n_rhs]``.
+
+    ``inputs`` are instance-minor ``[rows, N]`` arrays; ``lhs``/``rhs``
+    build their rows from ``row(input, row_index)`` loads of one instance
+    block (static recipes: python loops unrolled at trace time).  Padded
+    instances take ``pad_values`` per input; recipes make them contribute
+    nothing (every lhs row carries a responsibility, padded with 0).
+    """
+    interpret = _resolve_interpret(interpret)
+    N = inputs[0].shape[1]
+    block = min(block, N)
+    nb = pl.cdiv(N, block)
+    pad = nb * block - N
+    if pad:
+        inputs = [jnp.pad(x, ((0, 0), (0, pad)), constant_values=v)
+                  for x, v in zip(inputs, pad_values)]
+    na, nr = _round8(n_lhs), _round8(n_rhs)
+    out = pl.pallas_call(
+        functools.partial(_gram_kernel, n_in=len(inputs), lhs=lhs, rhs=rhs),
+        grid=(nb,),
+        in_specs=[pl.BlockSpec((x.shape[0], block), lambda bi: (0, bi))
+                  for x in inputs],
+        out_specs=pl.BlockSpec((na, nr), lambda bi: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((na, nr), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((na, block), jnp.float32),
+                        pltpu.VMEM((nr, block), jnp.float32)],
+        interpret=interpret,
+    )(*inputs)
+    return out[:n_lhs, :n_rhs]
+
+
+def _minor(x: jnp.ndarray) -> jnp.ndarray:
+    """[N, ...] -> [prod(...), N]: instance axis minor (the lane axis)."""
+    return x.reshape(x.shape[0], -1).T
+
+
+def _clg_gram(obs, y, r, h_mean, block, interpret):
+    """Gram of the CLG reduction.  Columns, per leaf f (width W):
+    ``o_a o_b`` (Do^2), ``o_a y`` (Do), ``y^2``, then for latent plates
+    ``o_a`` (Do) and ``y``; latent plates append the ``E[h]`` rows (K*L)
+    and a ones row.  Rows: ``r_k`` (K), then ``r_k E[h_kl]`` (K*L)."""
+    _, F, Do = obs.shape
+    K = r.shape[1]
+    L = 0 if h_mean is None else h_mean.shape[2]
+    inputs = [_minor(obs), _minor(y), _minor(r)]
+    if L:
+        inputs.append(_minor(h_mean))                      # [K*L, N]
+    O, Y, R, H = 0, 1, 2, 3
+
+    def lhs(row):
+        rows = [row(R, k) for k in range(K)]
+        rows += [row(R, k) * row(H, k * L + l)
+                 for k in range(K) for l in range(L)]
+        return rows
+
+    def rhs(row):
+        rows = []
+        for f in range(F):
+            o = [row(O, f * Do + a) for a in range(Do)]
+            yf = row(Y, f)
+            rows += [oa * ob for oa in o for ob in o]
+            rows += [oa * yf for oa in o]
+            rows.append(yf * yf)
+            if L:
+                rows += o + [yf]
+        if L:
+            rows += [row(H, i) for i in range(K * L)]
+            rows.append(jnp.ones_like(row(Y, 0)))
+        return rows
+
+    W = Do * Do + Do + 1 + (Do + 1 if L else 0)
+    n_rhs = F * W + (K * L + 1 if L else 0)
+    G = _gram(inputs, [0.0] * len(inputs), lhs, K * (1 + L), rhs, n_rhs,
+              block=block, interpret=interpret)
+    leaf = G[:, :F * W].reshape(K * (1 + L), F, W).transpose(1, 0, 2)
+    return leaf, G[:, F * W:]                 # [F, K(1+L), W], [K(1+L), .]
 
 
 def clg_suffstats(d: jnp.ndarray, y: jnp.ndarray, r: jnp.ndarray, *,
-                  block: int = 512, interpret: Optional[bool] = None
+                  block: int = 2048, interpret: Optional[bool] = None
                   ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """d: [N, F, D] design vectors; y: [N, F]; r: [N, K] responsibilities.
 
     Returns (sxx [F, K, D, D], sxy [F, K, D], syy [F, K]) — the RegSuffStats
     triple of repro.core.expfam (oracle: kernels.ref.clg_suffstats_ref).
     """
-    interpret = _resolve_interpret(interpret)
-    N, F, D = d.shape
+    F, D = d.shape[1], d.shape[2]
     K = r.shape[1]
-    block = min(block, N)
-    nb = pl.cdiv(N, block)
-    pad = nb * block - N
-    if pad:
-        d = jnp.pad(d, ((0, pad), (0, 0), (0, 0)))
-        y = jnp.pad(y, ((0, pad), (0, 0)))
-        r = jnp.pad(r, ((0, pad), (0, 0)))
-
-    # feature-major layouts
-    df = jnp.moveaxis(d, 1, 0)                # [F, N, D]
-    yf = jnp.moveaxis(y, 1, 0)                # [F, N]
-    rk = jnp.moveaxis(r, 1, 0)                # [K, N]
-
-    sxx, sxy, syy = pl.pallas_call(
-        functools.partial(_kernel, nb=nb),
-        grid=(F, K, nb),
-        in_specs=[
-            pl.BlockSpec((1, block, D), lambda f, k, bi: (f, bi, 0)),
-            pl.BlockSpec((1, block), lambda f, k, bi: (f, bi)),
-            pl.BlockSpec((1, block), lambda f, k, bi: (k, bi)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, D, D), lambda f, k, bi: (f, k, 0, 0)),
-            pl.BlockSpec((1, 1, D), lambda f, k, bi: (f, k, 0)),
-            pl.BlockSpec((1, 1), lambda f, k, bi: (f, k)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((F, K, D, D), jnp.float32),
-            jax.ShapeDtypeStruct((F, K, D), jnp.float32),
-            jax.ShapeDtypeStruct((F, K), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((D, D), jnp.float32),
-            pltpu.VMEM((D,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(df, yf, rk)
-    return sxx, sxy, syy
-
-
-def _latent_kernel(o_ref, hm_ref, y_ref, r_ref, shh_ref,
-                   sxx_ref, sxy_ref, syy_ref,
-                   sxx_scr, sxy_scr, syy_scr, rsum_scr, *,
-                   nb: int, Do: int, L: int):
-    bi = pl.program_id(2)
-
-    @pl.when(bi == 0)
-    def _init():
-        sxx_scr[...] = jnp.zeros_like(sxx_scr)
-        sxy_scr[...] = jnp.zeros_like(sxy_scr)
-        syy_scr[...] = jnp.zeros_like(syy_scr)
-        rsum_scr[...] = jnp.zeros_like(rsum_scr)
-
-    o = o_ref[0].astype(jnp.float32)          # [bn, Do]  (leaf f's design)
-    hm = hm_ref[0].astype(jnp.float32)        # [bn, L]   (component k's E[h])
-    y = y_ref[0].astype(jnp.float32)          # [bn]
-    r = r_ref[0].astype(jnp.float32)          # [bn]
-
-    u = jnp.concatenate([o, hm], axis=1)      # [bn, D] component-major design
-    uw = u * r[:, None]
-    sxx_scr[...] += jax.lax.dot_general(
-        uw, u, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)   # [D, D]
-    sxy_scr[...] += (uw * y[:, None]).sum(0)  # [D]
-    syy_scr[0] += (r * y * y).sum()
-    rsum_scr[0] += r.sum()
-
-    @pl.when(bi == nb - 1)
-    def _final():
-        # E[hh^T | z=k] = S_k + E[h]E[h]^T: the outer products above cover the
-        # mean part; the instance-independent covariance enters as rsum * S_k
-        # padded into the latent-latent block.
-        D = Do + L
-        corr = jnp.zeros((D, D), jnp.float32)
-        corr = corr.at[Do:, Do:].set(shh_ref[0])
-        sxx_ref[0, 0] = sxx_scr[...] + rsum_scr[0] * corr
-        sxy_ref[0, 0] = sxy_scr[...]
-        syy_ref[0, 0] = syy_scr[0]
+    leaf, _ = _clg_gram(d, y, r, None, block, interpret)   # [F, K, W]
+    sxx = leaf[..., :D * D].reshape(F, K, D, D)
+    sxy = leaf[..., D * D:D * D + D]
+    return sxx, sxy, leaf[..., D * D + D]
 
 
 def clg_suffstats_latent(obs: jnp.ndarray, h_mean: jnp.ndarray,
                          y: jnp.ndarray, r: jnp.ndarray, s_hh: jnp.ndarray, *,
-                         block: int = 512, interpret: Optional[bool] = None
+                         block: int = 2048, interpret: Optional[bool] = None
                          ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Fused latent-plate (FA/PPCA) suff-stats: component-major designs.
 
@@ -193,108 +211,47 @@ def clg_suffstats_latent(obs: jnp.ndarray, h_mean: jnp.ndarray,
     One pass over instances; nothing [N, K, L, L]-shaped is ever formed
     (oracle: kernels.ref.clg_suffstats_latent_ref).
     """
-    interpret = _resolve_interpret(interpret)
-    N, F, Do = obs.shape
+    F, Do = obs.shape[1], obs.shape[2]
     K, L = h_mean.shape[1], h_mean.shape[2]
-    D = Do + L
-    block = min(block, N)
-    nb = pl.cdiv(N, block)
-    pad = nb * block - N
-    if pad:
-        obs = jnp.pad(obs, ((0, pad), (0, 0), (0, 0)))
-        h_mean = jnp.pad(h_mean, ((0, pad), (0, 0), (0, 0)))
-        y = jnp.pad(y, ((0, pad), (0, 0)))
-        r = jnp.pad(r, ((0, pad), (0, 0)))  # r = 0 pads: contribute nothing
-
-    of = jnp.moveaxis(obs, 1, 0)              # [F, N, Do]
-    hk = jnp.moveaxis(h_mean, 1, 0)           # [K, N, L]
-    yf = jnp.moveaxis(y, 1, 0)                # [F, N]
-    rk = jnp.moveaxis(r, 1, 0)                # [K, N]
-    shh = jnp.asarray(s_hh, jnp.float32)      # [K, L, L]
-
-    sxx, sxy, syy = pl.pallas_call(
-        functools.partial(_latent_kernel, nb=nb, Do=Do, L=L),
-        grid=(F, K, nb),
-        in_specs=[
-            pl.BlockSpec((1, block, Do), lambda f, k, bi: (f, bi, 0)),
-            pl.BlockSpec((1, block, L), lambda f, k, bi: (k, bi, 0)),
-            pl.BlockSpec((1, block), lambda f, k, bi: (f, bi)),
-            pl.BlockSpec((1, block), lambda f, k, bi: (k, bi)),
-            pl.BlockSpec((1, L, L), lambda f, k, bi: (k, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, D, D), lambda f, k, bi: (f, k, 0, 0)),
-            pl.BlockSpec((1, 1, D), lambda f, k, bi: (f, k, 0)),
-            pl.BlockSpec((1, 1), lambda f, k, bi: (f, k)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((F, K, D, D), jnp.float32),
-            jax.ShapeDtypeStruct((F, K, D), jnp.float32),
-            jax.ShapeDtypeStruct((F, K), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((D, D), jnp.float32),
-            pltpu.VMEM((D,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(of, hk, yf, rk, shh)
-    return sxx, sxy, syy
-
-
-def _disc_kernel(x_ref, r_ref, out_ref, acc_scr, *, nb: int, C: int):
-    bi = pl.program_id(2)
-
-    @pl.when(bi == 0)
-    def _init():
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    x = x_ref[0]                              # [bn] int32
-    r = r_ref[0].astype(jnp.float32)          # [bn]
-    cols = jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], C), 1)
-    onehot = (cols == x[:, None]).astype(jnp.float32)      # [bn, C]
-    acc_scr[...] += (onehot * r[:, None]).sum(0)           # [C]
-
-    @pl.when(bi == nb - 1)
-    def _final():
-        out_ref[0, 0] = acc_scr[...]
+    leaf, tail = _clg_gram(obs, y, r, h_mean, block, interpret)
+    rk, rh = leaf[:, :K], leaf[:, K:].reshape(F, K, L, -1)
+    s_oo = rk[..., :Do * Do].reshape(F, K, Do, Do)
+    sxy_o = rk[..., Do * Do:Do * Do + Do]
+    syy = rk[..., Do * Do + Do]
+    o_at = Do * Do + Do + 1                   # start of the o_a, y columns
+    s_ho = rh[..., o_at:o_at + Do]                          # [F, K, L, Do]
+    sxy_h = rh[..., o_at + Do]                              # [F, K, L]
+    hh = tail[K:, :K * L].reshape(K, L, K, L)
+    hh = jnp.einsum("klkm->klm", hh)                        # k = k' blocks
+    hh = hh + tail[:K, K * L][:, None, None] * s_hh.astype(jnp.float32)
+    hh = jnp.broadcast_to(hh[None], (F, K, L, L))
+    top = jnp.concatenate([s_oo, jnp.swapaxes(s_ho, -1, -2)], axis=-1)
+    bot = jnp.concatenate([s_ho, hh], axis=-1)
+    sxx = jnp.concatenate([top, bot], axis=-2)
+    return sxx, jnp.concatenate([sxy_o, sxy_h], axis=-1), syy
 
 
 def clg_disc_counts(xd: jnp.ndarray, r: jnp.ndarray, C: int, *,
-                    block: int = 512, interpret: Optional[bool] = None
+                    block: int = 2048, interpret: Optional[bool] = None
                     ) -> jnp.ndarray:
     """xd: [N, Fd] int discrete leaves; r: [N, K] responsibilities.
 
     Returns disc [Fd, K, C] — the weighted one-hot reduction
     ``sum_n r[n,k] onehot(xd[n,f], C)`` that completes the d-VMP message
-    pytree (oracle: kernels.ref.clg_disc_counts_ref).  Same tiling scheme as
-    :func:`clg_suffstats`: grid (Fd, K, n_blocks), instance dim sequential,
-    [C] accumulator in VMEM scratch.
+    pytree (oracle: kernels.ref.clg_disc_counts_ref).  Same Gram kernel as
+    :func:`clg_suffstats`, with indicator rows ``[x_f == c]`` on the right.
     """
-    interpret = _resolve_interpret(interpret)
-    N, Fd = xd.shape
+    Fd = xd.shape[1]
     K = r.shape[1]
-    block = min(block, N)
-    nb = pl.cdiv(N, block)
-    pad = nb * block - N
-    if pad:
-        # padded instances get category -1: matches no iota column -> 0 count
-        xd = jnp.pad(xd, ((0, pad), (0, 0)), constant_values=-1)
-        r = jnp.pad(r, ((0, pad), (0, 0)))
 
-    xf = jnp.moveaxis(xd.astype(jnp.int32), 1, 0)          # [Fd, N]
-    rk = jnp.moveaxis(r, 1, 0)                             # [K, N]
+    def lhs(row):
+        return [row(1, k) for k in range(K)]
 
-    return pl.pallas_call(
-        functools.partial(_disc_kernel, nb=nb, C=C),
-        grid=(Fd, K, nb),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda f, k, bi: (f, bi)),
-            pl.BlockSpec((1, block), lambda f, k, bi: (k, bi)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, C), lambda f, k, bi: (f, k, 0)),
-        out_shape=jax.ShapeDtypeStruct((Fd, K, C), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((C,), jnp.float32)],
-        interpret=interpret,
-    )(xf, rk)
+    def rhs(row):
+        return [(row(0, f) == c).astype(jnp.float32)
+                for f in range(Fd) for c in range(C)]
+
+    # padded instances get category -1: they match no indicator row
+    G = _gram([_minor(xd.astype(jnp.int32)), _minor(r)], [-1, 0.0],
+              lhs, K, rhs, Fd * C, block=block, interpret=interpret)
+    return G.reshape(K, Fd, C).transpose(1, 0, 2)

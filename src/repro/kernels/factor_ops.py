@@ -23,6 +23,13 @@ accumulation over N tiles so arbitrarily wide factors stream through VMEM.
 All three tolerate ``-inf`` entries (structural zeros from evidence
 indicators) without producing NaNs.
 
+TPU layout: the last two dims of every block are either (8, 128)-aligned
+or the full extent of the array's last two dims.  Per-row results
+(``[B, M]``) therefore leave the kernels as ``[B, M, 1]`` columns, and the
+per-batch operands (``b [B, N]``, ``idx [B]``) enter with a unit axis
+(``[B, 1, N]``, ``[B, 1, 1]``); the wrappers restore the public shapes.
+Compile/interpret policy: ``clg_stats._resolve_interpret``.
+
 Oracles: ``repro.kernels.ref.{log_product_ref,log_marginalize_ref,
 evidence_select_ref}``.  Jit'd public wrappers: ``repro.kernels.ops``.
 """
@@ -30,11 +37,14 @@ evidence_select_ref}``.  Jit'd public wrappers: ``repro.kernels.ops``.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.clg_stats import _resolve_interpret
 
 NEG_INF = float("-inf")
 
@@ -45,30 +55,33 @@ NEG_INF = float("-inf")
 
 
 def _product_kernel(a_ref, b_ref, o_ref):
-    o_ref[0] = a_ref[0] + b_ref[0][None, :]
+    o_ref[0] = a_ref[0] + b_ref[0]                 # [bm, bn] + [1, bn]
 
 
 def log_product(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 256,
-                interpret: bool = True) -> jnp.ndarray:
+                bn: int = 2048, interpret: Optional[bool] = None
+                ) -> jnp.ndarray:
     """Log-space factor product of ``a`` with a sepset factor ``b``."""
     B, M, N = a.shape
-    bm = min(bm, M)
-    nm = pl.cdiv(M, bm)
-    pad_m = nm * bm - M
-    if pad_m:
-        a = jnp.pad(a, ((0, 0), (0, pad_m), (0, 0)))
+    bm, bn = min(bm, M), min(bn, N)
+    nm, nn = pl.cdiv(M, bm), pl.cdiv(N, bn)
+    pad_m, pad_n = nm * bm - M, nn * bn - N
+    b = b.astype(jnp.float32).reshape(B, 1, N)
+    if pad_m or pad_n:
+        a = jnp.pad(a, ((0, 0), (0, pad_m), (0, pad_n)))
+        b = jnp.pad(b, ((0, 0), (0, 0), (0, pad_n)))
     out = pl.pallas_call(
         _product_kernel,
-        grid=(B, nm),
+        grid=(B, nm, nn),
         in_specs=[
-            pl.BlockSpec((1, bm, N), lambda b_, mi: (b_, mi, 0)),
-            pl.BlockSpec((1, N), lambda b_, mi: (b_, 0)),
+            pl.BlockSpec((1, bm, bn), lambda b_, mi, ni: (b_, mi, ni)),
+            pl.BlockSpec((1, 1, bn), lambda b_, mi, ni: (b_, 0, ni)),
         ],
-        out_specs=pl.BlockSpec((1, bm, N), lambda b_, mi: (b_, mi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, nm * bm, N), jnp.float32),
-        interpret=interpret,
-    )(a.astype(jnp.float32), b.astype(jnp.float32))
-    return out[:, :M]
+        out_specs=pl.BlockSpec((1, bm, bn), lambda b_, mi, ni: (b_, mi, ni)),
+        out_shape=jax.ShapeDtypeStruct((B, nm * bm, nn * bn), jnp.float32),
+        interpret=_resolve_interpret(interpret),
+    )(a.astype(jnp.float32), b)
+    return out[:, :M, :N]
 
 
 # ---------------------------------------------------------------------------
@@ -85,12 +98,12 @@ def _marginalize_kernel(x_ref, o_ref, m_scr, s_scr, *, nn: int):
         s_scr[...] = jnp.zeros_like(s_scr)
 
     x = x_ref[0].astype(jnp.float32)           # [bm, bn]
-    m_prev = m_scr[...]                        # [bm]
-    m_new = jnp.maximum(m_prev, x.max(-1))
+    m_prev = m_scr[...]                        # [bm, 1]
+    m_new = jnp.maximum(m_prev, x.max(-1, keepdims=True))
     # safe center: where the running max is still -inf every exp() below is 0
     ms = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
     corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - ms), 0.0)
-    s_scr[...] = s_scr[...] * corr + jnp.exp(x - ms[:, None]).sum(-1)
+    s_scr[...] = s_scr[...] * corr + jnp.exp(x - ms).sum(-1, keepdims=True)
     m_scr[...] = m_new
 
     @pl.when(ni == nn - 1)
@@ -102,7 +115,7 @@ def _marginalize_kernel(x_ref, o_ref, m_scr, s_scr, *, nn: int):
 
 
 def log_marginalize(x: jnp.ndarray, *, bm: int = 256, bn: int = 256,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: Optional[bool] = None) -> jnp.ndarray:
     """logsumexp over the last axis of ``x [B, M, N]`` -> ``[B, M]``."""
     B, M, N = x.shape
     bm, bn = min(bm, M), min(bn, N)
@@ -115,15 +128,15 @@ def log_marginalize(x: jnp.ndarray, *, bm: int = 256, bn: int = 256,
         functools.partial(_marginalize_kernel, nn=nn),
         grid=(B, nm, nn),
         in_specs=[pl.BlockSpec((1, bm, bn), lambda b_, mi, ni: (b_, mi, ni))],
-        out_specs=pl.BlockSpec((1, bm), lambda b_, mi, ni: (b_, mi)),
-        out_shape=jax.ShapeDtypeStruct((B, nm * bm), jnp.float32),
+        out_specs=pl.BlockSpec((1, bm, 1), lambda b_, mi, ni: (b_, mi, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, nm * bm, 1), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((bm,), jnp.float32),
-            pltpu.VMEM((bm,), jnp.float32),
+            pltpu.VMEM((bm, 1), jnp.float32),
+            pltpu.VMEM((bm, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=_resolve_interpret(interpret),
     )(x.astype(jnp.float32))
-    return out[:, :M]
+    return out[:, :M, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -131,38 +144,47 @@ def log_marginalize(x: jnp.ndarray, *, bm: int = 256, bn: int = 256,
 # ---------------------------------------------------------------------------
 
 
-def _select_kernel(x_ref, i_ref, o_ref):
-    x = x_ref[0].astype(jnp.float32)           # [bm, N]
-    idx = i_ref[0, 0]
-    col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    o_ref[0] = jnp.where(col == idx, x, NEG_INF).max(-1)
+def _select_kernel(x_ref, i_ref, o_ref, *, bn: int):
+    ni = pl.program_id(2)
+
+    @pl.when(ni == 0)
+    def _init():
+        o_ref[...] = jnp.full_like(o_ref, NEG_INF)
+
+    x = x_ref[0].astype(jnp.float32)           # [bm, bn]
+    col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) + ni * bn
+    o_ref[0] = jnp.maximum(
+        o_ref[0], jnp.where(col == i_ref[0], x, NEG_INF).max(-1,
+                                                             keepdims=True))
 
 
 def evidence_select(x: jnp.ndarray, idx: jnp.ndarray, *, bm: int = 256,
-                    interpret: bool = True) -> jnp.ndarray:
+                    bn: int = 2048, interpret: Optional[bool] = None
+                    ) -> jnp.ndarray:
     """``x [B, M, N], idx [B] int`` -> ``[B, M]`` with ``out[b] = x[b,:,idx[b]]``.
 
     This is batched evidence reduction: each query instance clamps its own
     observed value, shrinking the factor by one axis in a single device call.
     """
     B, M, N = x.shape
-    bm = min(bm, M)
-    nm = pl.cdiv(M, bm)
-    pad_m = nm * bm - M
-    if pad_m:
-        x = jnp.pad(x, ((0, 0), (0, pad_m), (0, 0)), constant_values=NEG_INF)
+    bm, bn = min(bm, M), min(bn, N)
+    nm, nn = pl.cdiv(M, bm), pl.cdiv(N, bn)
+    pad_m, pad_n = nm * bm - M, nn * bn - N
+    if pad_m or pad_n:
+        x = jnp.pad(x, ((0, 0), (0, pad_m), (0, pad_n)),
+                    constant_values=NEG_INF)
     out = pl.pallas_call(
-        _select_kernel,
-        grid=(B, nm),
+        functools.partial(_select_kernel, bn=bn),
+        grid=(B, nm, nn),
         in_specs=[
-            pl.BlockSpec((1, bm, N), lambda b_, mi: (b_, mi, 0)),
-            pl.BlockSpec((1, 1), lambda b_, mi: (b_, 0)),
+            pl.BlockSpec((1, bm, bn), lambda b_, mi, ni: (b_, mi, ni)),
+            pl.BlockSpec((1, 1, 1), lambda b_, mi, ni: (b_, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bm), lambda b_, mi: (b_, mi)),
-        out_shape=jax.ShapeDtypeStruct((B, nm * bm), jnp.float32),
-        interpret=interpret,
-    )(x.astype(jnp.float32), idx.astype(jnp.int32).reshape(B, 1))
-    return out[:, :M]
+        out_specs=pl.BlockSpec((1, bm, 1), lambda b_, mi, ni: (b_, mi, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, nm * bm, 1), jnp.float32),
+        interpret=_resolve_interpret(interpret),
+    )(x.astype(jnp.float32), idx.astype(jnp.int32).reshape(B, 1, 1))
+    return out[:, :M, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -171,31 +193,31 @@ def evidence_select(x: jnp.ndarray, idx: jnp.ndarray, *, bm: int = 256,
 
 
 def _weak_marg_kernel(lw_ref, mu_ref, sg_ref, p_ref, mh_ref, sh_ref,
-                      *, N: int, n: int):
+                      *, n: int):
     lw = lw_ref[0].astype(jnp.float32)              # [bm, N]
-    bm = lw.shape[0]
-    m = lw.max(-1)                                  # [bm]
+    m = lw.max(-1, keepdims=True)                   # [bm, 1]
     ms = jnp.where(jnp.isfinite(m), m, 0.0)
-    w = jnp.where(jnp.isfinite(lw), jnp.exp(lw - ms[:, None]), 0.0)
-    s = w.sum(-1)                                   # [bm]
+    w = jnp.where(jnp.isfinite(lw), jnp.exp(lw - ms), 0.0)
+    s = w.sum(-1, keepdims=True)                    # [bm, 1]
     p_ref[0] = jnp.where(s > 0.0, ms + jnp.log(jnp.maximum(s, 1e-37)),
                          NEG_INF)
-    wn = w / jnp.maximum(s, 1e-37)[:, None]         # [bm, N] normalized
-    mu = mu_ref[0].astype(jnp.float32).reshape(bm, N, n)
-    sg = sg_ref[0].astype(jnp.float32).reshape(bm, N, n, n)
-    mu_hat = (wn[:, :, None] * mu).sum(1)           # [bm, n]
-    second = (wn[:, :, None, None]
-              * (sg + mu[:, :, :, None] * mu[:, :, None, :])).sum(1)
-    sg_hat = second - mu_hat[:, :, None] * mu_hat[:, None, :]
-    dead = (s <= 0.0)
-    eye = jnp.eye(n, dtype=jnp.float32)
-    mh_ref[0] = jnp.where(dead[:, None], 0.0, mu_hat)
-    sh_ref[0] = jnp.where(dead[:, None, None], eye[None], sg_hat
-                          ).reshape(bm, n * n)
+    wn = w / jnp.maximum(s, 1e-37)                  # [bm, N] normalized
+    dead = s <= 0.0
+    # moment i of the mixture and component covariance (i, j) are
+    # leading-axis slices: the mixture axis N stays on the lanes
+    mu = [mu_ref[0, i].astype(jnp.float32) for i in range(n)]   # [bm, N]
+    mu_hat = [(wn * mu_i).sum(-1, keepdims=True) for mu_i in mu]
+    for i in range(n):
+        mh_ref[0, i] = jnp.where(dead, 0.0, mu_hat[i])
+        for j in range(n):
+            second = (wn * (sg_ref[0, i * n + j].astype(jnp.float32)
+                            + mu[i] * mu[j])).sum(-1, keepdims=True)
+            sh_ref[0, i * n + j] = jnp.where(
+                dead, float(i == j), second - mu_hat[i] * mu_hat[j])
 
 
 def cg_weak_marg(logw: jnp.ndarray, mu: jnp.ndarray, sigma: jnp.ndarray,
-                 *, bm: int = 64, interpret: bool = True
+                 *, bm: int = 64, interpret: Optional[bool] = None
                  ) -> tuple:
     """Moment-matching weak marginal: collapse the mixture axis N.
 
@@ -217,28 +239,30 @@ def cg_weak_marg(logw: jnp.ndarray, mu: jnp.ndarray, sigma: jnp.ndarray,
                        constant_values=NEG_INF)
         mu = jnp.pad(mu, ((0, 0), (0, pad_m), (0, 0), (0, 0)))
         sigma = jnp.pad(sigma, ((0, 0), (0, pad_m), (0, 0), (0, 0), (0, 0)))
-    mu2 = mu.reshape(B, nm * bm, N * n)
-    sg2 = sigma.reshape(B, nm * bm, N * n * n)
+    Mp = nm * bm
+    mu2 = jnp.moveaxis(mu, -1, 1)                              # [B, n, Mp, N]
+    sg2 = jnp.moveaxis(sigma.reshape(B, Mp, N, n * n), -1, 1)  # [B, nn, Mp, N]
     p, mh, sh = pl.pallas_call(
-        functools.partial(_weak_marg_kernel, N=N, n=n),
+        functools.partial(_weak_marg_kernel, n=n),
         grid=(B, nm),
         in_specs=[
             pl.BlockSpec((1, bm, N), lambda b_, mi: (b_, mi, 0)),
-            pl.BlockSpec((1, bm, N * n), lambda b_, mi: (b_, mi, 0)),
-            pl.BlockSpec((1, bm, N * n * n), lambda b_, mi: (b_, mi, 0)),
+            pl.BlockSpec((1, n, bm, N), lambda b_, mi: (b_, 0, mi, 0)),
+            pl.BlockSpec((1, n * n, bm, N), lambda b_, mi: (b_, 0, mi, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bm), lambda b_, mi: (b_, mi)),
-            pl.BlockSpec((1, bm, n), lambda b_, mi: (b_, mi, 0)),
-            pl.BlockSpec((1, bm, n * n), lambda b_, mi: (b_, mi, 0)),
+            pl.BlockSpec((1, bm, 1), lambda b_, mi: (b_, mi, 0)),
+            pl.BlockSpec((1, n, bm, 1), lambda b_, mi: (b_, 0, mi, 0)),
+            pl.BlockSpec((1, n * n, bm, 1), lambda b_, mi: (b_, 0, mi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, nm * bm), jnp.float32),
-            jax.ShapeDtypeStruct((B, nm * bm, n), jnp.float32),
-            jax.ShapeDtypeStruct((B, nm * bm, n * n), jnp.float32),
+            jax.ShapeDtypeStruct((B, Mp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, n, Mp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, n * n, Mp, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=_resolve_interpret(interpret),
     )(logw.astype(jnp.float32), mu2.astype(jnp.float32),
       sg2.astype(jnp.float32))
-    return (p[:, :M], mh[:, :M].reshape(B, M, n),
-            sh[:, :M].reshape(B, M, n, n))
+    mh = jnp.moveaxis(mh[:, :, :M, 0], 1, -1)                  # [B, M, n]
+    sh = jnp.moveaxis(sh[:, :, :M, 0], 1, -1).reshape(B, M, n, n)
+    return p[:, :M, 0], mh, sh

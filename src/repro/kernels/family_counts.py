@@ -10,13 +10,15 @@ where ``code`` is the mixed-radix flattening of the family's (child,
 parents) columns.  Because the radix weights are per-family constants, the
 code of instance n under family m is a plain dot product
 
-    code[n, m] = sum_f strides[m, f] * xd[n, f]
+    code[m, n] = sum_f strides[m, f] * xd[n, f]
 
 (``strides[m, f] = 0`` for columns outside the family), so ONE pass over
-the instances scores every candidate family at once: grid (M,
-n_instance_blocks) with the instance dim minor (sequential), the [bn, Fd] x
-[Fd] code dot on the MXU and the [C] count accumulator in VMEM scratch —
-the same tiling scheme as ``clg_stats.clg_disc_counts``.
+the instances scores every candidate family at once: the grid is the
+instance blocks alone (sequential), the columns arrive instance-minor
+(``[Fd, block]``: instances on the 128-lane axis), each block forms the
+``[M, block]`` code matrix and, per family, adds the weighted one-hot
+histogram ``w [1, block] x onehot [C, block]^T`` on the MXU into the
+resident ``[M, C]`` output.
 
 Same compile/interpret policy as the other kernels
 (``clg_stats._resolve_interpret``).  Oracle: ``repro.kernels.ref.
@@ -36,32 +38,35 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.clg_stats import _resolve_interpret
 
 
-def _kernel(xd_ref, s_ref, w_ref, out_ref, acc_scr, *, nb: int, C: int):
-    bi = pl.program_id(1)
-
-    @pl.when(bi == 0)
+def _kernel(x_ref, s_ref, w_ref, out_ref, code_scr, *, Fd: int, C: int):
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-    xd = xd_ref[...].astype(jnp.float32)       # [bn, Fd]
-    s = s_ref[...].astype(jnp.float32)         # [1, Fd]  (family m's strides)
-    w = w_ref[...].astype(jnp.float32)         # [bn]
-    # mixed-radix flat configuration code of every instance under family m:
-    # integer-valued floats, exact well past any practical config count
-    code = jax.lax.dot_general(
-        xd, s, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)    # [bn, 1]
-    cols = jax.lax.broadcasted_iota(jnp.float32, (xd.shape[0], C), 1)
-    onehot = (cols == code).astype(jnp.float32)            # [bn, C]
-    acc_scr[...] += (onehot * w[:, None]).sum(0)           # [C]
+    # mixed-radix flat configuration code of every instance under every
+    # family: integer-valued floats, exact well past any practical config
+    # count (an f32 VPU multiply-add per column, no MXU rounding)
+    code = sum(s_ref[f].astype(jnp.float32)                    # [M, 1]
+               * x_ref[pl.ds(f, 1), :].astype(jnp.float32)     # [1, bn]
+               for f in range(Fd))                             # [M, bn]
+    code_scr[...] = code
+    w = w_ref[...].astype(jnp.float32)                         # [1, bn]
+    configs = jax.lax.broadcasted_iota(jnp.int32, (C, code.shape[1]),
+                                       0).astype(jnp.float32)
 
-    @pl.when(bi == nb - 1)
-    def _final():
-        out_ref[0] = acc_scr[...]
+    def family(m, carry):
+        onehot = (configs == code_scr[pl.ds(m, 1), :]).astype(jnp.float32)
+        out_ref[pl.ds(m, 1), :] += jax.lax.dot_general(
+            w, onehot, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)                # [1, C]
+        return carry
+
+    jax.lax.fori_loop(0, code.shape[0], family, 0)
 
 
 def family_counts(xd: jnp.ndarray, strides: jnp.ndarray, w: jnp.ndarray,
-                  C: int, *, block: int = 512,
+                  C: int, *, block: int = 2048,
                   interpret: Optional[bool] = None) -> jnp.ndarray:
     """xd: [N, Fd] int discrete columns; strides: [M, Fd] mixed-radix
     weights (0 outside the family); w: [N] instance weights/mask.
@@ -77,21 +82,25 @@ def family_counts(xd: jnp.ndarray, strides: jnp.ndarray, w: jnp.ndarray,
     block = min(block, N)
     nb = pl.cdiv(N, block)
     pad = nb * block - N
+    xt = xd.astype(jnp.int32).T                                # [Fd, N]
+    w = w.reshape(1, N)
     if pad:
         # padded instances carry w = 0: their (valid) code 0 adds nothing
-        xd = jnp.pad(xd, ((0, pad), (0, 0)))
-        w = jnp.pad(w, (0, pad))
+        xt = jnp.pad(xt, ((0, 0), (0, pad)))
+        w = jnp.pad(w, ((0, 0), (0, pad)))
+    # [Fd, M, 1]: column f of the strides is a leading-axis slice in-kernel
+    s = strides.astype(jnp.int32).T[:, :, None]
 
     return pl.pallas_call(
-        functools.partial(_kernel, nb=nb, C=C),
-        grid=(M, nb),
+        functools.partial(_kernel, Fd=Fd, C=C),
+        grid=(nb,),
         in_specs=[
-            pl.BlockSpec((block, Fd), lambda m, bi: (bi, 0)),
-            pl.BlockSpec((1, Fd), lambda m, bi: (m, 0)),
-            pl.BlockSpec((block,), lambda m, bi: (bi,)),
+            pl.BlockSpec((Fd, block), lambda bi: (0, bi)),
+            pl.BlockSpec((Fd, M, 1), lambda bi: (0, 0, 0)),
+            pl.BlockSpec((1, block), lambda bi: (0, bi)),
         ],
-        out_specs=pl.BlockSpec((1, C), lambda m, bi: (m, 0)),
+        out_specs=pl.BlockSpec((M, C), lambda bi: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((M, C), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((C,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((M, block), jnp.float32)],
         interpret=interpret,
-    )(xd.astype(jnp.int32), strides.astype(jnp.int32), w)
+    )(xt, s, w)

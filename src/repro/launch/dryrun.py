@@ -27,6 +27,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import obs
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.base import INPUT_SHAPES, InputShape, ModelConfig
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import data_axes_of, make_production_mesh
 from repro.nn import transformer as T
 from repro.sharding import decode_state_specs, param_specs, train_state_specs
@@ -298,6 +299,7 @@ def main(argv=None) -> int:
     ap.add_argument("--hlo-dir", default=None,
                     help="also dump post-SPMD HLO text here")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     archs = ARCH_IDS if args.arch == "all" else [args.arch]
     shapes = list(INPUT_SHAPES) if args.shape == "all" else [args.shape]

@@ -30,6 +30,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.amidst_pgm import PGM_WORKLOADS
 from repro.core import dvmp, vmp
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import data_axes_of, make_production_mesh
 
 
@@ -98,6 +99,7 @@ def main(argv=None) -> int:
     ap.add_argument("--mesh", default="single", choices=["single", "multi"])
     ap.add_argument("--out", default="results/dryrun_pgm")
     args = ap.parse_args(argv)
+    use_compile_cache()
     rec = run_one(args.workload, args.n, args.mesh == "multi", args.out)
     print(json.dumps(rec, indent=1))
     return 0

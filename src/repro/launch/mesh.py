@@ -10,7 +10,14 @@ from typing import Tuple
 
 import jax
 
-from repro.core.compat import make_mesh
+
+def make_mesh(axis_shapes, axis_names, **kwargs):
+    """``jax.make_mesh`` with every axis Auto: ``shard_map`` bodies and the
+    sharding annotations of this repo are written for Auto axes, and jax
+    defaults new meshes to Explicit ones."""
+    kwargs.setdefault(
+        "axis_types", (jax.sharding.AxisType.Auto,) * len(axis_names))
+    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -21,6 +21,8 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.launch.cache import use_compile_cache
+
 
 def _serve_lm(args) -> int:
     import jax
@@ -175,6 +177,7 @@ def main(argv=None) -> int:
     ap.add_argument("--swap", action="store_true",
                     help="hot-swap the model mid-run")
     args = ap.parse_args(argv)
+    use_compile_cache()
     if args.arch is not None:
         return _serve_lm(args)
     return _serve_pgm(args)

@@ -13,6 +13,8 @@ from functools import partial
 
 import numpy as np
 
+from repro.launch.cache import use_compile_cache
+
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
@@ -32,6 +34,7 @@ def main(argv=None) -> int:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     import jax
     import jax.numpy as jnp

@@ -232,7 +232,7 @@ def attention_decode_ctx_parallel(q, cache: KVCache, mesh, *,
     """
     from functools import partial as _partial
     from jax.sharding import PartitionSpec as P
-    from repro.core.compat import shard_map
+    from jax import shard_map
 
     B, _, Hq, D = q.shape
     C = cache.k.shape[1]
@@ -277,7 +277,7 @@ def cache_update_ctx_parallel(cache: KVCache, k_new, v_new, mesh, *,
     writes; everyone else passes its slice through."""
     from functools import partial as _partial
     from jax.sharding import PartitionSpec as P
-    from repro.core.compat import shard_map
+    from jax import shard_map
 
     C = cache.k.shape[1]
     s = mesh.shape[model_axis]

@@ -33,7 +33,7 @@ from typing import Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.core.compat import shard_map
+from jax import shard_map
 
 from repro.configs.base import MoEConfig
 from repro.nn.layers import he_init

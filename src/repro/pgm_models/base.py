@@ -13,6 +13,8 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core import dvmp, expfam as ef, vmp
 from repro.core.dag import (BayesianNetwork, CLGCPD, DAG, MultinomialCPD,
@@ -48,14 +50,27 @@ class Model:
 
     # -- data plumbing ----------------------------------------------------------
 
-    def _as_batch(self, data) -> Batch:
-        if isinstance(data, Batch):
-            return data
+    def _as_batch(self, data, sharding=None) -> Batch:
+        """``data`` as one Batch.  With ``sharding`` (the d-VMP data
+        sharding) host data goes straight to its shards, so no device holds
+        the whole set on the way; a Batch already placed so stays put."""
+        if sharding is None:
+            if isinstance(data, Batch):
+                return data
+            if isinstance(data, DataStream):
+                return data.collect()
+            xc = jnp.asarray(data, jnp.float32)
+            return Batch(xc, jnp.zeros((xc.shape[0], 0), jnp.int32),
+                         jnp.ones(xc.shape[0], jnp.float32))
         if isinstance(data, DataStream):
-            return data.collect()
-        xc = jnp.asarray(data, jnp.float32)
-        return Batch(xc, jnp.zeros((xc.shape[0], 0), jnp.int32),
-                     jnp.ones(xc.shape[0], jnp.float32))
+            xcs, xds = zip(*data.chunks())
+            data = Batch(np.concatenate(xcs), np.concatenate(xds),
+                         np.ones(sum(len(x) for x in xcs), np.float32))
+        elif not isinstance(data, Batch):
+            xc = np.asarray(data, np.float32)
+            data = Batch(xc, np.zeros((xc.shape[0], 0), np.int32),
+                         np.ones(xc.shape[0], np.float32))
+        return jax.device_put(data, sharding)
 
     # -- learning (paper Code Fragments 7, 9, 12) --------------------------------
 
@@ -91,7 +106,9 @@ class Model:
                 # (sources need not be restartable)
                 xc, xd = chunks[0]
                 data = Batch(xc, xd, jnp.ones(xc.shape[0], jnp.float32))
-        batch = self._as_batch(data)
+        batch = self._as_batch(
+            data, None if mesh is None
+            else NamedSharding(mesh, PartitionSpec(tuple(data_axes))))
         prior = self._chained_prior
         r_fixed = self.supervised_r(batch)
 
@@ -126,8 +143,6 @@ class Model:
                              window: Optional[int] = None) -> float:
         """Streaming Bayesian updating over pre-chunked data (ROADMAP item:
         ``stream_fit`` underneath ``update_model``)."""
-        import numpy as np
-
         from repro.core import streaming
 
         state = streaming.stream_init(self._chained_prior, self.posterior)
@@ -254,8 +269,6 @@ class Model:
     # -- pretty print (paper Code Fragment 8) --------------------------------------
 
     def __str__(self) -> str:
-        import numpy as np
-
         p = self.posterior
         lay = self.cp.layout
         lines = [f"{type(self).__name__} (Bayesian posterior):"]

@@ -41,7 +41,7 @@ def test_dvmp_matches_single_device_vmp():
         cp = vmp.compile_plate(spec)
         prior = vmp.default_prior(cp); init = vmp.symmetry_broken(prior, k3)
         st = vmp.vmp_fit(cp, prior, init, x, xd, 50, 1e-6)
-        from repro.core.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((8,), ("data",))
         st2 = dvmp.dvmp_fit(cp, prior, init, x, xd, mesh, ("data",), 50, 1e-6)
         assert np.allclose(st.post.reg.m, st2.post.reg.m, atol=1e-3), "means differ"
@@ -67,7 +67,7 @@ def test_sharded_train_step_matches_single_device():
         lr_fn = opt.cosine_schedule(1e-3, 10, 100)
         s0 = ts.init_train_state(params)
         _, m0 = jax.jit(partial(ts.train_step, cfg=cfg, lr_fn=lr_fn))(s0, batch)
-        from repro.core.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((2, 4), ("data", "model"))
         sh = T.Shardings(mesh=mesh, data_axes=("data",), model_axis="model")
         s1 = ts.init_train_state(params)
@@ -89,7 +89,7 @@ def test_ctx_parallel_decode_matches_single_device():
         params = T.init_model(key, cfg)
         B, cap = 8, 64
         st0 = T.init_decode_state(params, cfg, B, cap)
-        from repro.core.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((2, 4), ("data", "model"))
         sh = T.Shardings(mesh=mesh, data_axes=("data",), model_axis="model",
                          shard_heads=False)
@@ -122,7 +122,7 @@ def test_moe_ep_matches_dense_local():
         p1 = M.init_moe(key, d, ff, cfg, ep_shards=1)
         y1, aux1 = M.apply_moe(p1, x, cfg, mesh=None)
         # EP over 4 model shards (same canonical weights, re-laid-out)
-        from repro.core.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((2, 4), ("data", "model"))
         p4 = M.init_moe(key, d, ff, cfg, ep_shards=4)
         y4, aux4 = M.apply_moe(p4, x, cfg, mesh=mesh)

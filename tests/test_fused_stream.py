@@ -277,7 +277,7 @@ def test_dvmp_latent_plate_matches_single_device():
     """d-VMP psums the lazy latent-block message pytree correctly: the
     mesh fit equals the single-device fit on an FA-mixture plate."""
     from repro.core import dvmp
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
 
     spec = PlateSpec(n_features=3, latent_card=2, latent_dim=2)
     cp = vmp.compile_plate(spec)
@@ -349,7 +349,7 @@ def test_stream_fit_donation_keeps_inputs_alive():
 
 def test_dvmp_programs_are_cached():
     from repro.core import dvmp
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
 
     stream, _, _ = gmm_stream(64, 2, 3, seed=1)
     full = stream.collect()

@@ -317,7 +317,7 @@ def test_local_step_with_metrics_chunked():
 
 def test_dvmp_fit_with_metrics_single_device_mesh():
     from repro.core import dvmp
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
 
     spec = PlateSpec(n_features=3, latent_card=2)
     cp = vmp.compile_plate(spec)

@@ -348,7 +348,7 @@ def test_mesh_replica_parity_with_single_device():
         from repro.data import synthetic as syn
         from repro.pgm_models import GaussianMixture
         from repro.serve.engine import PGMQueryEngine
-        from repro.core.compat import make_mesh
+        from repro.launch.mesh import make_mesh
 
         stream, _, _ = syn.gmm_stream(256, 3, 4, seed=1)
         m = GaussianMixture(stream.attributes, n_states=3)

@@ -1,5 +1,8 @@
 """End-to-end behaviour of the whole system (paper-level claims)."""
 
+import os
+import subprocess
+import sys
 from functools import partial
 
 import jax
@@ -90,3 +93,25 @@ def test_streaming_pgm_and_nn_share_drift_machinery():
     for _ in range(10):
         st, ph = drift_update(st, jnp.asarray(-8.0))
     assert float(ph) > 3.0
+
+
+def test_importing_the_package_leaves_the_backend_alone():
+    """No module initializes a JAX backend when imported: on a TPU host that
+    would take hold of the chip in every process that imports it (a child
+    process that needs the chip then fails or hangs)."""
+    code = """
+import importlib, pkgutil
+import repro
+from jax._src import xla_bridge
+names = [m.name for m in pkgutil.walk_packages(repro.__path__, "repro.")]
+for name in names:
+    importlib.import_module(name)
+    assert not xla_bridge._backends, name
+print("IMPORTED", len(names))
+"""
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=src)
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "IMPORTED" in out.stdout
