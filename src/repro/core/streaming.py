@@ -70,6 +70,7 @@ def drift_update(state: DriftState, score: jnp.ndarray, *,
     return DriftState(mean=mean, cum=cum, cum_min=cum_min, t=t), ph
 
 
+@jax.named_scope("streaming.drift")
 def drift_gate(dstate: DriftState, score: jnp.ndarray, chained, tempered, *,
                drift_threshold: float):
     """Page-Hinkley test + prior selection, as pure traced ops.
@@ -266,7 +267,6 @@ def stream_update(
                                    fit_fn)
     if obs.enabled():
         obs.emit_stream_events(info)
-        obs.emit_kernel_counts(site="stream_update")
     return new_state, info
 
 
@@ -362,7 +362,6 @@ def stream_fit(
                                        chunk=chunk)
         if obs.enabled():
             obs.emit_stream_events(info)
-            obs.emit_kernel_counts(site="stream_fit")
         return state, info
     infos = []
     for t0 in range(0, T, window):
@@ -379,5 +378,4 @@ def stream_fit(
     info = {k: jnp.concatenate([i[k] for i in infos]) for k in infos[0]}
     if obs.enabled():
         obs.emit_stream_events(info)
-        obs.emit_kernel_counts(site="stream_fit")
     return state, info

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 
 from repro.core import expfam as ef
 from repro.core.dag import PlateSpec
-from repro.obs import sink as obs_sink
 from repro.obs.metrics import LocalStepMetrics
 
 
@@ -264,7 +263,6 @@ def _reduce_reg(cp: CompiledPlate, obs: jnp.ndarray, y: jnp.ndarray,
     lay = cp.layout
     L = lay.L
     if L == 0:
-        obs_sink.count_kernel(f"clg_suffstats:{backend}")
         if backend == "pallas":
             from repro.kernels import clg_stats
 
@@ -274,7 +272,6 @@ def _reduce_reg(cp: CompiledPlate, obs: jnp.ndarray, y: jnp.ndarray,
             sxy = jnp.einsum("nfa,nf,nk->fka", obs, y, r)
             syy = jnp.einsum("nf,nf,nk->fk", y, y, r)
         return sxx, None, sxy, syy
-    obs_sink.count_kernel(f"clg_suffstats_latent:{backend}")
     if backend == "pallas":
         from repro.kernels import clg_stats
 
@@ -305,7 +302,6 @@ def _reduce_disc(cp: CompiledPlate, xd: jnp.ndarray, r: jnp.ndarray,
                  backend: str) -> jnp.ndarray:
     """Discrete-leaf one-hot count reduction -> [Fd, K, C]."""
     lay = cp.layout
-    obs_sink.count_kernel(f"clg_disc_counts:{backend}")
     if backend == "pallas":
         from repro.kernels import clg_stats
 
@@ -437,6 +433,7 @@ def _local_step_body(cp: CompiledPlate, params: PlateParams, xc: jnp.ndarray,
     return stats, r
 
 
+@jax.named_scope("vmp.local_step")
 def local_step(cp: CompiledPlate, params: PlateParams, xc: jnp.ndarray,
                xd: jnp.ndarray, mask: jnp.ndarray,
                r_fixed: Optional[jnp.ndarray] = None, *,
@@ -513,6 +510,7 @@ def local_step(cp: CompiledPlate, params: PlateParams, xc: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("vmp.global_update")
 def global_update(prior: PlateParams, stats: PlateStats) -> PlateParams:
     """posterior natural params = prior natural params + summed messages."""
     mix = ef.dirichlet_update(prior.mix, stats.counts)

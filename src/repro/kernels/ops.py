@@ -6,17 +6,10 @@ traced — never at import, which would initialize the backend and take hold
 of the chip in any process that imports this module: compiled natively
 when the default jax backend is a TPU, interpret mode (python semantics of
 the same kernel body) elsewhere.
-
-Every wrapper is counted (``obs.count_kernel``, host side, OUTSIDE the
-jit boundary — the jitted program itself is unchanged): when obs is
-enabled, each call bumps a ``<kernel>:<pallas|interpret>`` dispatch
-counter, snapshotted into ``kernel_dispatch`` JSONL events by the
-streaming and serving drivers.
 """
 
 from __future__ import annotations
 
-import functools
 from functools import partial
 
 import jax
@@ -32,41 +25,24 @@ from repro.kernels.factor_ops import (cg_weak_marg as _cgweak,
                                       log_product as _logprod)
 from repro.kernels.flash_attn import flash_attention as _flash
 from repro.kernels.ssd_scan import ssd_scan as _ssd
-from repro.obs import sink as obs_sink
-
-def _counted(kernel: str):
-    """Host-side dispatch counter around a jitted kernel wrapper."""
-    def deco(fn):
-        @functools.wraps(fn)
-        def wrapper(*args, **kwargs):
-            if obs_sink.enabled():
-                mode = "interpret" if _resolve_interpret(None) else "pallas"
-                obs_sink.count_kernel(f"{kernel}:{mode}")
-            return fn(*args, **kwargs)
-        return wrapper
-    return deco
 
 
-@_counted("flash_attention")
 @partial(jax.jit, static_argnames=("causal", "window", "bq", "bk"))
 def flash_attention(q, k, v, *, causal=True, window=None, bq=128, bk=128):
     return _flash(q, k, v, causal=causal, window=window, bq=bq, bk=bk,
                   interpret=_resolve_interpret(None))
 
 
-@_counted("ssd_scan")
 @partial(jax.jit, static_argnames=("chunk",))
 def ssd_scan(x, dt, A, B, C, chunk=128):
     return _ssd(x, dt, A, B, C, chunk, interpret=_resolve_interpret(None))
 
 
-@_counted("clg_suffstats")
 @partial(jax.jit, static_argnames=("block",))
 def clg_suffstats(d, y, r, *, block=2048):
     return _clg(d, y, r, block=block)
 
 
-@_counted("clg_seq_suffstats")
 @partial(jax.jit, static_argnames=("block",))
 def clg_seq_suffstats(d, y, r, *, block=2048):
     """Sequence-batch CLG suff-stats: flattens the ``[B, T]`` leading dims
@@ -80,43 +56,36 @@ def clg_seq_suffstats(d, y, r, *, block=2048):
                 r.reshape(B * T, r.shape[2]), block=block)
 
 
-@_counted("clg_suffstats_latent")
 @partial(jax.jit, static_argnames=("block",))
 def clg_suffstats_latent(obs, h_mean, y, r, s_hh, *, block=2048):
     return _clg_latent(obs, h_mean, y, r, s_hh, block=block)
 
 
-@_counted("clg_disc_counts")
 @partial(jax.jit, static_argnames=("C", "block"))
 def clg_disc_counts(xd, r, C, *, block=2048):
     return _clg_disc(xd, r, C, block=block)
 
 
-@_counted("family_counts")
 @partial(jax.jit, static_argnames=("C", "block"))
 def family_counts(xd, strides, w, C, *, block=2048):
     return _famcounts(xd, strides, w, C, block=block)
 
 
-@_counted("log_product")
 @partial(jax.jit, static_argnames=("bm", "bn"))
 def log_product(a, b, *, bm=256, bn=2048):
     return _logprod(a, b, bm=bm, bn=bn)
 
 
-@_counted("log_marginalize")
 @partial(jax.jit, static_argnames=("bm", "bn"))
 def log_marginalize(x, *, bm=256, bn=256):
     return _logmarg(x, bm=bm, bn=bn)
 
 
-@_counted("evidence_select")
 @partial(jax.jit, static_argnames=("bm", "bn"))
 def evidence_select(x, idx, *, bm=256, bn=2048):
     return _evsel(x, idx, bm=bm, bn=bn)
 
 
-@_counted("cg_weak_marg")
 @partial(jax.jit, static_argnames=("bm",))
 def cg_weak_marg(logw, mu, sigma, *, bm=64):
     return _cgweak(logw, mu, sigma, bm=bm)

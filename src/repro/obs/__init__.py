@@ -11,12 +11,16 @@ manager.
 Quick start::
 
     REPRO_OBS=basic  python ...   # JSONL events -> $REPRO_OBS_PATH
-    REPRO_OBS=trace  python ...   # + host latency spans
+    REPRO_OBS=trace  python ...   # + host latency spans as JSONL events
 
     from repro import obs
     with obs.span("my.region", tag="x") as sp:
         ...
     obs.emit("metric", name="elbo", value=-1.23)
+
+Spans are ``jax.profiler`` annotations at every level: under a profiler
+trace (``repro.obs.profile``) they sit on the host plane beside the
+device ops, on the same clock.
 
 See ``obs/sink.py`` for the event schema and README "Observability".
 """
@@ -24,30 +28,27 @@ See ``obs/sink.py`` for the event schema and README "Observability".
 from repro.obs.agg import (REGISTRY, MetricsRegistry, merge_snapshots,
                            quantile_from_snapshot)
 from repro.obs.sink import (BASIC, EVENT_SCHEMA, OFF, TRACE, configure,
-                            count_kernel, emit, emit_kernel_counts,
-                            emit_stream_events, enabled, estimate,
-                            kernel_counts, level, log, register, registered,
+                            emit, emit_stream_events, enabled, estimate,
+                            level, log, register, registered,
                             validate_obs_events)
 from repro.obs.trace import current_span, span
-from repro.obs.export import (chrome_trace, default_prometheus_text,
-                              prometheus_text, write_chrome_trace)
+from repro.obs.export import default_prometheus_text, prometheus_text
 from repro.obs.health import HealthTracker
 from repro.obs.metrics import (DvmpMetrics, LocalStepMetrics,
-                               StreamBatchMetrics, TemporalFitMetrics)
+                               StreamBatchMetrics, TemporalFitMetrics,
+                               UpdateCounters)
 
 __all__ = [
     "OFF", "BASIC", "TRACE", "EVENT_SCHEMA",
     "configure", "enabled", "level",
     "emit", "log", "span", "current_span",
-    "count_kernel", "kernel_counts", "emit_kernel_counts",
     "emit_stream_events",
     "register", "registered", "estimate",
     "validate_obs_events",
     "REGISTRY", "MetricsRegistry", "merge_snapshots",
     "quantile_from_snapshot",
     "prometheus_text", "default_prometheus_text",
-    "chrome_trace", "write_chrome_trace",
     "HealthTracker",
     "StreamBatchMetrics", "TemporalFitMetrics", "LocalStepMetrics",
-    "DvmpMetrics",
+    "DvmpMetrics", "UpdateCounters",
 ]

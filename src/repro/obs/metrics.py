@@ -60,3 +60,16 @@ class DvmpMetrics(NamedTuple):
 
     shard_n: Any   # [n_shards] per-device effective instance counts
     sweeps: Any    # scalar: sweeps-to-convergence of the distributed fit
+
+
+class UpdateCounters(NamedTuple):
+    """What one ``Model.update_model`` call did, as its fit counted it
+    in-graph (``model.last_update``).  The fields are the fit's own
+    arrays, left unread on the device: keeping them costs no sync."""
+
+    sweeps: Any     # VMP sweeps per batch ([T] on the stream path)
+    passes: Any     # local_step passes per batch: the sweeps, plus one
+                    # scoring pass per batch on the stream path
+    drifted: Any    # bool per batch: drift test fired ([T]); None where
+                    # the path runs no drift test
+    instances: Any  # instances absorbed by the call

@@ -5,10 +5,9 @@ The measurement substrate every serving/perf PR reads from (ROADMAP:
 need a counter source).  Three pieces:
 
 * **level knob** — ``REPRO_OBS=off|basic|trace`` (default ``off``).
-  ``off`` is a zero-overhead no-op: every ``emit``/``count_kernel`` call
-  is a single integer compare, spans return a cached null context and no
-  file is ever opened.  ``basic`` emits structured events (logs, stream
-  batch metrics, drift, serve buckets, kernel dispatch counts).
+  ``off`` is a zero-overhead no-op: every ``emit`` call is a single
+  integer compare and no file is ever opened.  ``basic`` emits
+  structured events (logs, stream batch metrics, drift, serve buckets).
   ``trace`` additionally emits host-side latency spans (``obs.trace``).
 
 * **JSONL sink** — every event is one JSON line appended to
@@ -24,13 +23,6 @@ need a counter source).  Three pieces:
   BENCH_* config blocks stamp analytical FLOP/byte estimates next to the
   measured inst/s, and each estimate is also recorded as a
   ``bench_estimate`` event.
-
-Kernel-backend dispatch counters live here too (:func:`count_kernel`):
-the suff-stats backends (``vmp._reduce_reg``/``_reduce_disc``) and the
-``kernels/ops.py`` public wrappers bump a ``<kernel>:<backend>`` counter
-at host-dispatch time.  Jitted callers dispatch once per TRACE (not per
-device execution) — the counts answer "which backend did this program
-take", not "how many times did the kernel run on device".
 """
 
 from __future__ import annotations
@@ -93,8 +85,6 @@ EVENT_SCHEMA: Dict[str, Tuple[str, ...]] = {
     "serve_worker": ("worker", "action", "requeued"),
     # streaming-state snapshot written (resilience/checkpoint.py)
     "checkpoint": ("t", "path", "reason"),
-    # kernel-backend dispatch counter snapshot
-    "kernel_dispatch": ("counts",),
     # registry estimator output (e.g. analytical HLO FLOP/byte model)
     "bench_estimate": ("name", "estimate"),
     # per-replica health score snapshot (serve/queue.py supervisor)
@@ -117,7 +107,6 @@ class _State:
         self.seq = 0
         self.fh: Optional[io.TextIOBase] = None
         self.lock = threading.Lock()
-        self.kernel_counts: Dict[str, int] = {}
         self.registry: Dict[str, Any] = {}
 
 
@@ -152,8 +141,6 @@ def configure(level: Optional[str] = None, path: Optional[str] = None,
                 _STATE.fh.close()
                 _STATE.fh = None
             _STATE.path = path
-        if reset_counters:
-            _STATE.kernel_counts.clear()
     if reset_counters:
         _agg.REGISTRY.reset()
     return prev
@@ -197,35 +184,6 @@ def log(msg: str, component: Optional[str] = None, **fields: Any) -> None:
     print(msg, file=sys.stderr, flush=True)
     if _STATE.level >= BASIC:
         emit("log", msg=msg, component=component, **fields)
-
-
-# ---------------------------------------------------------------------------
-# kernel-backend dispatch counters
-# ---------------------------------------------------------------------------
-
-
-def count_kernel(name: str) -> None:
-    """Bump the host-dispatch counter for ``<kernel>:<backend>``.
-
-    Called by the suff-stats backend dispatchers and the kernels/ops.py
-    wrappers.  Single dict update when enabled, one integer compare when
-    off.  Jitted callers hit this at trace time (once per compile)."""
-    if _STATE.level < BASIC:
-        return
-    with _STATE.lock:
-        _STATE.kernel_counts[name] = _STATE.kernel_counts.get(name, 0) + 1
-    _agg.REGISTRY.counter("kernel_dispatch_total", kernel=name).inc()
-
-
-def kernel_counts() -> Dict[str, int]:
-    return dict(_STATE.kernel_counts)
-
-
-def emit_kernel_counts(**extra: Any) -> None:
-    """Snapshot the dispatch counters into a ``kernel_dispatch`` event."""
-    if _STATE.level < BASIC or not _STATE.kernel_counts:
-        return
-    emit("kernel_dispatch", counts=dict(_STATE.kernel_counts), **extra)
 
 
 # ---------------------------------------------------------------------------

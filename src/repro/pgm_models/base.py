@@ -16,10 +16,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
+from repro import obs
 from repro.core import dvmp, expfam as ef, vmp
 from repro.core.dag import (BayesianNetwork, CLGCPD, DAG, MultinomialCPD,
                             PlateSpec, Variables)
 from repro.data.stream import Attribute, Batch, DataStream, FINITE, REAL
+from repro.obs.metrics import UpdateCounters
 
 
 class Model:
@@ -34,6 +36,7 @@ class Model:
         self.posterior = vmp.symmetry_broken(self.prior, jax.random.PRNGKey(seed))
         self._chained_prior = self.prior  # Eq. 3 accumulator
         self.n_seen = 0
+        self.last_update: Optional[UpdateCounters] = None
         # suff-stats reduction schedule (vmp.local_step): backend None ->
         # pallas where the kernels compile natively, einsum elsewhere
         self.backend = backend if backend is not None else vmp.default_backend()
@@ -92,81 +95,122 @@ class Model:
         [T, B, F] on device); ``stream_window=w`` keeps the stack on the
         host and replays device-sliced windows of w batches instead —
         bounded device memory for streams larger than memory.
+
+        The call is an ``update_model`` span (``repro.obs``) whose children
+        are ``update_model.ingest`` (host data to device arrays),
+        ``update_model.dispatch`` (the call into the fit) and
+        ``update_model.wait`` (reading the ELBO and the instance count,
+        which waits for the device).  The fit's in-graph counters are kept
+        in :attr:`last_update` (:class:`~repro.obs.metrics.UpdateCounters`)
+        as device arrays that nothing in the call reads.
         """
+        with obs.span("update_model"):
+            return self._update_model(data, sweeps, tol, mesh,
+                                      tuple(data_axes), stream_window)
+
+    def _update_model(self, data, sweeps, tol, mesh, data_axes,
+                      window) -> float:
+        with obs.span("update_model.ingest"):
+            batch, chunks = self._ingest(data, mesh, data_axes, window)
+        if batch is None:
+            return self._update_model_stream(chunks, sweeps=sweeps, tol=tol,
+                                             window=window)
+        prior = self._chained_prior
+        with obs.span("update_model.dispatch"):
+            r_fixed = self.supervised_r(batch)
+            if r_fixed is not None:
+                # conjugate closed form: one local step + global update
+                stats, _ = vmp.local_step(
+                    self.cp, self.posterior, batch.xc, batch.xd, batch.mask,
+                    r_fixed, backend=self.backend, chunk=self.chunk
+                )
+                post = vmp.global_update(prior, stats)
+                elbo = vmp.elbo(self.cp, prior, post, stats)
+                done = np.int32(0)
+            else:
+                if mesh is None:
+                    st = vmp.vmp_fit(self.cp, prior, self.posterior,
+                                     batch.xc, batch.xd, sweeps, tol,
+                                     batch.mask, self.backend, self.chunk)
+                else:
+                    st = dvmp.dvmp_fit(self.cp, prior, self.posterior,
+                                       batch.xc, batch.xd, mesh, data_axes,
+                                       sweeps, tol, mask=batch.mask,
+                                       backend=self.backend, chunk=self.chunk)
+                post, elbo, done = st.post, st.elbo, st.sweep
+            n = batch.mask.sum()
+        self.last_update = UpdateCounters(
+            sweeps=done, passes=done if r_fixed is None else np.int32(1),
+            drifted=None, instances=n)
+        with obs.span("update_model.wait"):
+            e = float(elbo)
+            self.n_seen += int(n)
+        self.posterior = post
+        self._chained_prior = post      # Eq. 3: posterior -> next prior
+        return e
+
+    def _ingest(self, data, mesh, data_axes, window):
+        """Host data to device arrays: ``(batch, None)`` for the one-shot
+        fit, or ``(None, chunks)`` for the streaming path, ``chunks``
+        stacked into one ``(xcs, xds)`` pair when their shapes agree."""
         if (mesh is None and isinstance(data, DataStream)
                 and type(self).supervised_r is Model.supervised_r):
             chunks = [(jnp.asarray(xc, jnp.float32), jnp.asarray(xd))
                       for xc, xd in data.chunks()]
             if len(chunks) > 1:
-                return self._update_model_stream(chunks, sweeps=sweeps,
-                                                 tol=tol,
-                                                 window=stream_window)
+                if len({(xc.shape, xd.shape) for xc, xd in chunks}) > 1:
+                    return None, chunks
+                # windowed replay keeps the stack host-resident (numpy)
+                stack = np.stack if window is not None else jnp.stack
+                return None, [(stack([xc for xc, _ in chunks]),
+                               stack([xd for _, xd in chunks]))]
             if chunks:
                 # single chunk: reuse it instead of re-running the source
                 # (sources need not be restartable)
                 xc, xd = chunks[0]
                 data = Batch(xc, xd, jnp.ones(xc.shape[0], jnp.float32))
-        batch = self._as_batch(
+        return self._as_batch(
             data, None if mesh is None
-            else NamedSharding(mesh, PartitionSpec(tuple(data_axes))))
-        prior = self._chained_prior
-        r_fixed = self.supervised_r(batch)
-
-        if r_fixed is not None:
-            # conjugate closed form: one local step + global update
-            stats, _ = vmp.local_step(
-                self.cp, self.posterior, batch.xc, batch.xd, batch.mask,
-                r_fixed, backend=self.backend, chunk=self.chunk
-            )
-            if mesh is not None:
-                stats = jax.tree_util.tree_map(lambda s: s, stats)  # already global
-            post = vmp.global_update(prior, stats)
-            e = float(vmp.elbo(self.cp, prior, post, stats))
-        elif mesh is None:
-            st = vmp.vmp_fit(self.cp, prior, self.posterior,
-                             batch.xc, batch.xd, sweeps, tol, batch.mask,
-                             self.backend, self.chunk)
-            post, e = st.post, float(st.elbo)
-        else:
-            st = dvmp.dvmp_fit(self.cp, prior, self.posterior, batch.xc,
-                               batch.xd, mesh, data_axes, sweeps, tol,
-                               mask=batch.mask, backend=self.backend,
-                               chunk=self.chunk)
-            post, e = st.post, float(st.elbo)
-
-        self.posterior = post
-        self._chained_prior = post      # Eq. 3: posterior -> next prior
-        self.n_seen += int(batch.mask.sum())
-        return e
+            else NamedSharding(mesh, PartitionSpec(tuple(data_axes)))), None
 
     def _update_model_stream(self, chunks, *, sweeps: int, tol: float,
                              window: Optional[int] = None) -> float:
         """Streaming Bayesian updating over pre-chunked data (ROADMAP item:
-        ``stream_fit`` underneath ``update_model``)."""
+        ``stream_fit`` underneath ``update_model``): one stacked
+        ``(xcs, xds)`` pair replays in the ``stream_fit`` scan, ragged
+        chunks one ``stream_update`` each."""
         from repro.core import streaming
 
-        state = streaming.stream_init(self._chained_prior, self.posterior)
-        stacked = len({(xc.shape, xd.shape) for xc, xd in chunks}) == 1
-        if stacked:
-            # windowed replay keeps the stack host-resident (numpy)
-            stack = np.stack if window is not None else jnp.stack
-            xcs = stack([xc for xc, _ in chunks])
-            xds = stack([xd for _, xd in chunks])
-            state, info = streaming.stream_fit(
-                self.cp, self.prior, state, xcs, xds,
-                sweeps=sweeps, tol=tol, backend=self.backend,
-                chunk=self.chunk, window=window)
-            e = float(info["elbo"][-1])
-        else:
-            for xc, xd in chunks:
-                state, info = streaming.stream_update(
-                    self.cp, self.prior, state, xc, xd,
+        with obs.span("update_model.dispatch"):
+            state = streaming.stream_init(self._chained_prior, self.posterior)
+            if len(chunks) == 1:
+                (xcs, xds), = chunks
+                state, info = streaming.stream_fit(
+                    self.cp, self.prior, state, xcs, xds,
                     sweeps=sweeps, tol=tol, backend=self.backend,
-                    chunk=self.chunk)
-            e = float(info["elbo"])
+                    chunk=self.chunk, window=window)
+                done, drifted = info["sweeps"], info["drifted"]
+                elbo = info["elbo"][-1]
+            else:
+                infos = []
+                for xc, xd in chunks:
+                    state, info = streaming.stream_update(
+                        self.cp, self.prior, state, xc, xd,
+                        sweeps=sweeps, tol=tol, backend=self.backend,
+                        chunk=self.chunk)
+                    infos.append(info)
+                done = jnp.stack([i["sweeps"] for i in infos])
+                drifted = jnp.stack([i["drifted"] for i in infos])
+                elbo = info["elbo"]
+        # one scoring pass per batch before its sweeps
+        self.last_update = UpdateCounters(sweeps=done, passes=done + 1,
+                                          drifted=drifted,
+                                          instances=state.n_seen)
+        with obs.span("update_model.wait"):
+            e = float(elbo)
+            self.n_seen += int(state.n_seen)
         self.posterior = state.post
         self._chained_prior = state.post
-        self.n_seen += int(state.n_seen)
         return e
 
     # -- queries -----------------------------------------------------------------

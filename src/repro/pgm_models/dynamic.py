@@ -592,7 +592,6 @@ def seq_stream_fit(model, batches, *, sweeps: int = 10, tol: float = 1e-5,
     model.n_quarantined = int(n_quar)
     if obs_sink.enabled():
         obs_sink.emit_stream_events(info)
-        obs_sink.emit_kernel_counts(site="seq_stream_fit")
     return info
 
 

@@ -288,9 +288,9 @@ class PGMQueryEngine:
         batch size, compile-vs-execute split (from the junction tree's
         ``last_run``), cache hit/miss and wall latency — as a
         ``serve.bucket`` span plus a ``serve_bucket`` event, with a
-        ``serve_flush`` summary and a kernel-dispatch snapshot at the end.
-        Disabled (the default), this method runs the pre-obs code path with
-        one integer compare per bucket added.
+        ``serve_flush`` summary at the end.  Disabled (the default), this
+        method runs the pre-obs code path with one integer compare per
+        bucket added; the spans are profiler annotations at every level.
         """
         import time as _time
 
@@ -324,7 +324,6 @@ class PGMQueryEngine:
         if obs.enabled():
             obs.emit("serve_flush", mode=self.mode, n_queries=queue_depth,
                      n_buckets=len(groups))
-            obs.emit_kernel_counts(site="serve.flush")
         # SUBMISSION order, not bucket order: callers pair results with
         # requests positionally, and qid is the submission sequence number
         done.sort(key=lambda q: q.qid)

@@ -154,7 +154,8 @@ class PlanCache:
             try:
                 if self.fault_hook is not None:
                     self.fault_hook(key)
-                fn = build()
+                with obs.span("serve.plan.build"):
+                    fn = build()
                 break
             except Exception as e:
                 attempt += 1

@@ -73,16 +73,21 @@ class ServeTicket:
     """Future-like handle for one submitted query.
 
     ``result(timeout)`` blocks until the micro-batch containing the query
-    flushes; ``query`` then holds the answered :class:`PGMQuery`.
+    flushes; ``query`` then holds the answered :class:`PGMQuery`.  The
+    monotonic stamps ``submitted_s <= flush_s <= done_s`` split its
+    latency into queue wait and flush; ``flush_s`` stays None for a ticket
+    no flush took (shed).
     """
 
-    __slots__ = ("rid", "deadline_s", "submitted_s", "done_s", "query",
-                 "error", "deadline_miss", "trigger", "_event", "_lock")
+    __slots__ = ("rid", "deadline_s", "submitted_s", "flush_s", "done_s",
+                 "query", "error", "deadline_miss", "trigger", "_event",
+                 "_lock")
 
     def __init__(self, rid: int, deadline_s: float, submitted_s: float):
         self.rid = rid
         self.deadline_s = deadline_s        # monotonic-clock deadline
         self.submitted_s = submitted_s
+        self.flush_s: Optional[float] = None   # its flush took the bucket
         self.done_s: Optional[float] = None
         self.query: Optional[PGMQuery] = None
         self.error: Optional[BaseException] = None
@@ -397,8 +402,9 @@ class AsyncPGMServer:
                 # fault injection: a raise here kills the worker with the
                 # bucket still registered in-flight (supervised recovery)
                 hook(widx, bucket)
-            failed = self._flush_bucket(engines[widx % len(engines)], bucket,
-                                        trigger)
+            with obs.span("serve.worker.flush"):
+                failed = self._flush_bucket(engines[widx % len(engines)],
+                                            bucket, trigger)
             if self.health is not None:
                 # t0 predates the flush hook, so an injected stall shows up
                 # in this worker's latency EWMA exactly like a real one
@@ -418,6 +424,7 @@ class AsyncPGMServer:
         try:
             with eng._serve_lock:
                 for t, target, evidence, payload in bucket.items:
+                    t.flush_s = now
                     pairs.append((t, eng.submit(target, evidence, payload)))
                 eng.flush()
         except BaseException as e:          # fail the tickets, never hang them

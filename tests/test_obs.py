@@ -1,7 +1,7 @@
 """Observability: JSONL sink + schema validation, span tracing, streaming
-metrics (drift events on a concept switch), serve-path telemetry, kernel
-dispatch counters — and the zero-overhead guarantee that ``REPRO_OBS=off``
-leaves every numeric output bit-identical and emits nothing."""
+metrics (drift events on a concept switch), serve-path telemetry — and
+the zero-overhead guarantee that ``REPRO_OBS=off`` leaves every numeric
+output bit-identical and emits nothing."""
 
 import contextlib
 import json
@@ -185,17 +185,22 @@ def test_serve_exact_telemetry(tmp_path):
     assert all(b["latency_us"] > 0 and b["execute_us"] >= 0 for b in buckets)
     assert {b["queue_depth"] for b in buckets} == {3, 2}
 
-    # span nesting: flush spans are roots, bucket/compile/execute have parents
+    # span nesting: flush spans are roots, bucket/build/compile/execute
+    # have parents; a plan compile runs inside the cache miss's build
     spans = {e["span_id"]: e for e in evs if e["event"] == "span"}
     names = [s["name"] for s in spans.values()]
-    for n in ("serve.flush", "serve.bucket", "jt.compile", "jt.execute"):
+    for n in ("serve.flush", "serve.bucket", "serve.plan.build",
+              "jt.compile", "jt.execute"):
         assert n in names, n
+    assert names.count("serve.plan.build") == 2    # one per compiled plan
     for s in spans.values():
         if s["name"] == "serve.flush":
             assert s["parent_id"] is None
         elif s["name"] == "serve.bucket":
             assert spans[s["parent_id"]]["name"] == "serve.flush"
-        else:   # jt.compile / jt.execute nest under their bucket
+        elif s["name"] == "jt.compile":
+            assert spans[s["parent_id"]]["name"] == "serve.plan.build"
+        else:   # serve.plan.build / jt.execute nest under their bucket
             assert spans[s["parent_id"]]["name"] == "serve.bucket"
 
 
@@ -249,43 +254,6 @@ def test_serve_vmp_mode_telemetry(tmp_path):
     np.testing.assert_allclose(
         np.stack([q.result for q in done]),
         np.asarray(m.posterior_z(batch))[3:6], atol=1e-5)
-
-
-# ---------------------------------------------------------------------------
-# kernel dispatch counters
-# ---------------------------------------------------------------------------
-
-
-def test_kernel_dispatch_counts(tmp_path):
-    from repro.kernels import ops
-
-    with _obs_to(tmp_path, level="basic") as path:
-        assert obs.kernel_counts() == {}
-        x = jnp.zeros((2, 4, 8))
-        ops.log_marginalize(x)
-        ops.log_marginalize(x)                 # host-side: counted per call
-        ops.log_product(x, jnp.zeros((2, 8)))
-        kc = obs.kernel_counts()
-        obs.emit_kernel_counts(site="test")
-        counts = obs.validate_obs_events(path)
-        evs = _events(path)
-
-    (lm_key,) = [k for k in kc if k.startswith("log_marginalize:")]
-    (lp_key,) = [k for k in kc if k.startswith("log_product:")]
-    assert kc[lm_key] == 2 and kc[lp_key] == 1
-    assert counts["kernel_dispatch"] == 1
-    ev = [e for e in evs if e["event"] == "kernel_dispatch"][0]
-    assert ev["counts"] == kc and ev["site"] == "test"
-
-
-def test_kernel_counters_off_cost_nothing(tmp_path):
-    from repro.kernels import ops
-
-    with _obs_to(tmp_path, level="off"):
-        ops.log_marginalize(jnp.zeros((2, 4, 8)))
-        assert obs.kernel_counts() == {}
-        obs.emit_kernel_counts()               # no counts, no file
-        assert not (tmp_path / "events.jsonl").exists()
 
 
 # ---------------------------------------------------------------------------

@@ -1,6 +1,6 @@
 """The obs aggregation tier: metrics registry (counters / gauges /
 log-bucketed histograms with exact-rank quantiles), snapshot merging,
-Prometheus + Chrome-trace exporters, replica health scoring, and the
+the Prometheus exporter, replica health scoring, and the
 degraded-replica dispatch bias in ``AsyncPGMServer`` — plus the span
 error-stamping regression test and the off-vs-trace bit-identity of the
 new serving paths."""
@@ -132,7 +132,7 @@ def test_merge_rejects_mismatched_bucket_configs():
 
 
 # ---------------------------------------------------------------------------
-# exporters (golden outputs)
+# exporter (golden output)
 # ---------------------------------------------------------------------------
 
 
@@ -154,38 +154,6 @@ def test_prometheus_text_golden():
         'lat_ms_bucket{route="a",le="+Inf"} 3\n'
         'lat_ms_sum{route="a"} 24.5\n'
         'lat_ms_count{route="a"} 3\n')
-
-
-def test_chrome_trace_golden():
-    spans = [
-        {"ts": 100.0001, "seq": 2, "run": "r1", "event": "span",
-         "name": "serve.flush", "dur_us": 100.0, "span_id": 1,
-         "parent_id": None, "tid": 7},
-        {"ts": 100.00005, "seq": 1, "run": "r1", "event": "span",
-         "name": "serve.bucket", "dur_us": 50.0, "span_id": 2,
-         "parent_id": 1, "tid": 7, "batch": 4},
-        {"ts": 100.0, "seq": 3, "run": "r1", "event": "metric",
-         "name": "x", "value": 1},                       # skipped
-    ]
-    tr = export.chrome_trace(spans)
-    assert tr == {"traceEvents": [
-        {"name": "process_name", "ph": "M", "pid": 1, "tid": 0,
-         "args": {"name": "obs run r1"}},
-        {"name": "serve.flush", "ph": "X", "ts": 100.0001 * 1e6 - 100.0,
-         "dur": 100.0, "pid": 1, "tid": 7, "args": {"span_id": 1}},
-        {"name": "serve.bucket", "ph": "X", "ts": 100.00005 * 1e6 - 50.0,
-         "dur": 50.0, "pid": 1, "tid": 7,
-         "args": {"batch": 4, "span_id": 2, "parent_id": 1}},
-    ], "displayTimeUnit": "ms"}
-
-
-def test_write_chrome_trace_roundtrip(tmp_path):
-    out = str(tmp_path / "trace.json")
-    spans = [{"ts": 1.0, "seq": 1, "run": "r", "event": "span", "name": "a",
-              "dur_us": 2.0, "span_id": 1, "parent_id": None, "tid": 0}]
-    export.write_chrome_trace([json.dumps(s) for s in spans], out)
-    with open(out) as fh:
-        assert len(json.load(fh)["traceEvents"]) == 2   # metadata + span
 
 
 # ---------------------------------------------------------------------------
@@ -318,13 +286,10 @@ def test_slow_flush_drops_health_score_and_biases_dispatch(tmp_path):
         slo = [e for e in _events(path) if e["event"] == "slo"][-1]
         assert slo["p50_ms"] <= slo["p95_ms"] <= slo["p99_ms"]
         assert 0.0 <= slo["miss_rate"] <= 1.0
-        # the run exports: Prometheus snapshot + Chrome trace both render
+        # the run exports: the Prometheus snapshot renders
         text = export.prometheus_text(agg.REGISTRY.snapshot())
         assert "serve_request_ms_bucket" in text
         assert "replica_score" in text
-        trace = export.write_chrome_trace(path, str(tmp_path / "trace.json"))
-        xs = [e for e in trace["traceEvents"] if e["ph"] == "X"]
-        assert xs and all(e["dur"] >= 0 for e in xs)
 
 
 def test_serve_with_health_off_vs_trace_bit_identical(tmp_path):
