@@ -312,6 +312,34 @@ def _reduce_disc(cp: CompiledPlate, xd: jnp.ndarray, r: jnp.ndarray,
     return counts * cp.card_mask[:, None, :]
 
 
+@jax.named_scope("vmp.disc_lookup")
+def _disc_loglik(e_logtheta: jnp.ndarray, xd: jnp.ndarray) -> jnp.ndarray:
+    """ll[n, k] = sum_f e_logtheta[f, k, xd[n, f]] -> [N, K].
+
+    A compare-select chain per leaf and category, not a gather: the chain
+    fuses into the elementwise work that consumes it, where a gather of
+    [N, Fd] indices into the tiny [Fd, K, C] table runs as its own slow
+    program with layout copies on either side.  Leaves are unrolled (the
+    trace is O(Fd * C)): selected over [N, Fd, K] at once, the compiler
+    keeps per-category masks and table slices apart, with a layout copy for
+    each slice.  The numbers are ``take_along_axis``'s bit for bit: each
+    selected entry is copied unchanged, the leaves are summed in order, an
+    index in [-C, 0) wraps once, and one outside [-C, C) reads NaN, which
+    the streaming quarantine relies on.
+    """
+    Fd, K, C = e_logtheta.shape
+    xi = xd.astype(jnp.int32)
+    ll = None
+    for f in range(Fd):
+        x = xi[:, f, None]                                     # [N, 1]
+        leaf = jnp.full((xi.shape[0], K), jnp.nan, e_logtheta.dtype)
+        for c in range(C):
+            leaf = jnp.where((x == c) | (x == c - C), e_logtheta[f, :, c],
+                             leaf)
+        ll = leaf if ll is None else ll + leaf
+    return ll
+
+
 def _local_step_body(cp: CompiledPlate, params: PlateParams, xc: jnp.ndarray,
                      xd: jnp.ndarray, mask: jnp.ndarray,
                      r_fixed: Optional[jnp.ndarray], backend: str,
@@ -380,11 +408,7 @@ def _local_step_body(cp: CompiledPlate, params: PlateParams, xc: jnp.ndarray,
     # discrete leaves
     if lay.Fd > 0:
         e_logtheta = ef.dirichlet_expected_logprob(params.disc)  # [Fd, K, C]
-        ll_disc = jnp.take_along_axis(
-            jnp.transpose(e_logtheta, (0, 2, 1))[None],          # [1, Fd, C, K]
-            xd.astype(jnp.int32)[..., None, None],               # [N, Fd, 1, 1]
-            axis=2,
-        )[..., 0, :].sum(1)                                      # [N, K]
+        ll_disc = _disc_loglik(e_logtheta, xd)                   # [N, K]
     else:
         ll_disc = jnp.zeros((N, K))
 

@@ -131,3 +131,29 @@ def test_cg_weak_marg(one_chip):
                                                         interpret=False),
              one_chip, ((B, M, W), jnp.float32), ((B, M, W, n), jnp.float32),
              ((B, M, W, n, n), jnp.float32))
+
+
+def test_disc_lookup_fuses(one_chip, monkeypatch):
+    # the discrete-leaf term of the local step at the drift cell's batch:
+    # a compare-select chain under its scope, with no gather left in it
+    from repro.core import vmp
+
+    monkeypatch.setenv("REPRO_PALLAS_COMPILE", "1")
+    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    _, _, spec = _plate("nb_mixed")
+    cp = vmp.compile_plate(spec)
+    lay, n = cp.layout, 1 << 18
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        vmp.default_prior(cp))
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in (((n, lay.F), jnp.float32), ((n, lay.Fd), jnp.int32),
+                          ((n,), jnp.float32))]
+    text = jax.jit(lambda p, xc, xd, m: vmp.local_step(
+        cp, p, xc, xd, m, backend="pallas")).lower(
+            params, *args).compile().as_text()
+    lines = text.splitlines()
+    gathers = [ln for ln in lines if " gather(" in ln
+               and ("vmp.disc_lookup" in ln or "take_along_axis" in ln)]
+    assert not gathers, gathers[0]
+    assert any("vmp.disc_lookup" in ln for ln in lines)
