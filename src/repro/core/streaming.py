@@ -24,6 +24,10 @@ Two drivers share one step body (:func:`_stream_step`):
   drift test and prior tempering inside the scan body and the
   ``StreamState`` buffers donated, so the whole stream replay is a single
   resident device program (no per-batch host round-trip or dispatch).
+
+``Model.update_model`` on equal-shape chunks calls :func:`_stream_call`,
+which runs the :func:`stream_fit` scan inside one program together with
+the stack, the state set-up and the read-out around it.
 """
 
 from __future__ import annotations
@@ -291,6 +295,39 @@ def _stream_fit_scan(cp, base_prior, state, xcs, xds, masks, *, sweeps, tol,
                             drift_threshold, forget, backend, chunk, fit_fn)
 
     return jax.lax.scan(step, state, (xcs, xds, masks))
+
+
+@partial(
+    jax.jit,
+    static_argnums=(0,),
+    static_argnames=("sweeps", "tol", "drift_threshold", "forget",
+                     "backend", "chunk"),
+)
+def _stream_call(cp, base_prior, chained, post, xcs, xds, *, sweeps, tol,
+                 backend, chunk, drift_threshold=5.0, forget=0.3):
+    """One streaming ``Model.update_model`` call as ONE device program.
+
+    ``xcs``/``xds`` are tuples of T equal-shape per-chunk arrays, each
+    already copied to the device on its own (so the staging of chunk t+1
+    overlaps the copy of chunk t); the stack, the all-real masks and the
+    fresh :class:`StreamState` (from ``chained`` and ``post``, no drift
+    history) are made in-graph, the batches replay through the
+    :func:`stream_fit` scan, and what the caller reads is computed in the
+    same program.  Nothing is donated: the state is born inside.
+
+    Returns ``(post, info, passes, elbo, n_seen)``: the posterior after the
+    last batch, the per-batch info columns of :func:`stream_fit`, the
+    local-step passes per batch (its sweeps plus the scoring pass), the
+    last batch's ELBO and the instances absorbed.
+    """
+    xcs, xds = jnp.stack(xcs), jnp.stack(xds)
+    state, info = _stream_fit_scan(
+        cp, base_prior, stream_init(chained, post), xcs, xds,
+        jnp.ones(xcs.shape[:2]), sweeps=sweeps, tol=tol,
+        drift_threshold=drift_threshold, forget=forget, backend=backend,
+        chunk=chunk)
+    return (state.post, info, info["sweeps"] + 1, info["elbo"][-1],
+            state.n_seen)
 
 
 def stream_fit(

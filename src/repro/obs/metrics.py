@@ -64,8 +64,9 @@ class DvmpMetrics(NamedTuple):
 
 class UpdateCounters(NamedTuple):
     """What one ``Model.update_model`` call did, as its fit counted it
-    in-graph (``model.last_update``).  The fields are the fit's own
-    arrays, left unread on the device: keeping them costs no sync."""
+    in-graph (``model.last_update``).  The array fields are the fit's
+    own, left unread on the device: keeping them costs no sync.
+    ``launches`` is counted on the host by the path the call took."""
 
     sweeps: Any     # VMP sweeps per batch ([T] on the stream path)
     passes: Any     # local_step passes per batch: the sweeps, plus one
@@ -73,3 +74,7 @@ class UpdateCounters(NamedTuple):
     drifted: Any    # bool per batch: drift test fired ([T]); None where
                     # the path runs no drift test
     instances: Any  # instances absorbed by the call
+    launches: int   # jitted fit programs the call launched, a host int:
+                    # 1 for one fit (fused stream call, vmp_fit, dvmp_fit),
+                    # one per window of a windowed replay, one vmp_fit per
+                    # ragged chunk (stream_update), 0 for the closed form

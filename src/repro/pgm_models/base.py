@@ -86,23 +86,26 @@ class Model:
         posterior becomes the prior for the new data.  Returns the ELBO.
 
         A multi-batch ``DataStream`` (a source yielding several chunks)
-        routes through ``streaming``: equal-shape chunks are stacked and
-        replayed by ``stream_fit`` in ONE jitted ``lax.scan`` (drift test +
-        tempering resident on device); ragged chunk shapes fall back to the
-        per-batch ``stream_update`` loop.  Single-chunk streams, raw arrays
-        and ``Batch``es keep the one-shot VMP fit below.  The stacked
-        replay is whole-stream-resident by default (the scan consumes
-        [T, B, F] on device); ``stream_window=w`` keeps the stack on the
-        host and replays device-sliced windows of w batches instead —
-        bounded device memory for streams larger than memory.
+        routes through ``streaming``: equal-shape chunks are copied to the
+        device one by one and the call is ONE device program that stacks
+        them and replays them in the ``stream_fit`` ``lax.scan`` (drift
+        test + tempering resident on device); ragged chunk shapes fall
+        back to the per-batch ``stream_update`` loop.  Single-chunk
+        streams, raw arrays and ``Batch``es keep the one-shot VMP fit
+        below.  The stacked replay is whole-stream-resident by default
+        (the scan consumes [T, B, F] on device); ``stream_window=w`` keeps
+        the stack on the host and replays device-sliced windows of w
+        batches instead — bounded device memory for streams larger than
+        memory.
 
         The call is an ``update_model`` span (``repro.obs``) whose children
         are ``update_model.ingest`` (host data to device arrays),
         ``update_model.dispatch`` (the call into the fit) and
         ``update_model.wait`` (reading the ELBO and the instance count,
-        which waits for the device).  The fit's in-graph counters are kept
-        in :attr:`last_update` (:class:`~repro.obs.metrics.UpdateCounters`)
-        as device arrays that nothing in the call reads.
+        which waits for the device; one transfer on the stream path).  The
+        fit's in-graph counters are kept in :attr:`last_update`
+        (:class:`~repro.obs.metrics.UpdateCounters`) as device arrays that
+        nothing in the call reads.
         """
         with obs.span("update_model"):
             return self._update_model(data, sweeps, tol, mesh,
@@ -111,7 +114,7 @@ class Model:
     def _update_model(self, data, sweeps, tol, mesh, data_axes,
                       window) -> float:
         with obs.span("update_model.ingest"):
-            batch, chunks = self._ingest(data, mesh, data_axes, window)
+            batch, chunks = self._ingest(data, mesh, data_axes)
         if batch is None:
             return self._update_model_stream(chunks, sweeps=sweeps, tol=tol,
                                              window=window)
@@ -141,7 +144,7 @@ class Model:
             n = batch.mask.sum()
         self.last_update = UpdateCounters(
             sweeps=done, passes=done if r_fixed is None else np.int32(1),
-            drifted=None, instances=n)
+            drifted=None, instances=n, launches=int(r_fixed is None))
         with obs.span("update_model.wait"):
             e = float(elbo)
             self.n_seen += int(n)
@@ -149,21 +152,16 @@ class Model:
         self._chained_prior = post      # Eq. 3: posterior -> next prior
         return e
 
-    def _ingest(self, data, mesh, data_axes, window):
+    def _ingest(self, data, mesh, data_axes):
         """Host data to device arrays: ``(batch, None)`` for the one-shot
-        fit, or ``(None, chunks)`` for the streaming path, ``chunks``
-        stacked into one ``(xcs, xds)`` pair when their shapes agree."""
+        fit, or ``(None, chunks)`` for the streaming path, each chunk's
+        ``(xc, xd)`` copied to the device on its own."""
         if (mesh is None and isinstance(data, DataStream)
                 and type(self).supervised_r is Model.supervised_r):
             chunks = [(jnp.asarray(xc, jnp.float32), jnp.asarray(xd))
                       for xc, xd in data.chunks()]
             if len(chunks) > 1:
-                if len({(xc.shape, xd.shape) for xc, xd in chunks}) > 1:
-                    return None, chunks
-                # windowed replay keeps the stack host-resident (numpy)
-                stack = np.stack if window is not None else jnp.stack
-                return None, [(stack([xc for xc, _ in chunks]),
-                               stack([xd for _, xd in chunks]))]
+                return None, chunks
             if chunks:
                 # single chunk: reuse it instead of re-running the source
                 # (sources need not be restartable)
@@ -176,42 +174,55 @@ class Model:
     def _update_model_stream(self, chunks, *, sweeps: int, tol: float,
                              window: Optional[int] = None) -> float:
         """Streaming Bayesian updating over pre-chunked data (ROADMAP item:
-        ``stream_fit`` underneath ``update_model``): one stacked
-        ``(xcs, xds)`` pair replays in the ``stream_fit`` scan, ragged
-        chunks one ``stream_update`` each."""
+        ``stream_fit`` underneath ``update_model``).  Equal-shape chunks
+        run as one program (``streaming._stream_call``: stack, state
+        set-up, the ``stream_fit`` scan and the read-out); with ``window``
+        they are stacked on the host and replayed by ``stream_fit`` a
+        window at a time; ragged chunks run one ``stream_update`` each."""
         from repro.core import streaming
 
+        kw = dict(sweeps=sweeps, tol=tol, backend=self.backend,
+                  chunk=self.chunk)
         with obs.span("update_model.dispatch"):
-            state = streaming.stream_init(self._chained_prior, self.posterior)
-            if len(chunks) == 1:
-                (xcs, xds), = chunks
-                state, info = streaming.stream_fit(
-                    self.cp, self.prior, state, xcs, xds,
-                    sweeps=sweeps, tol=tol, backend=self.backend,
-                    chunk=self.chunk, window=window)
-                done, drifted = info["sweeps"], info["drifted"]
-                elbo = info["elbo"][-1]
+            xcs, xds = zip(*chunks)
+            equal = len({(xc.shape, xd.shape) for xc, xd in chunks}) == 1
+            if equal and window is None:
+                post, info, passes, elbo, n = streaming._stream_call(
+                    self.cp, self.prior, self._chained_prior, self.posterior,
+                    xcs, xds, **kw)
+                if obs.enabled():
+                    obs.emit_stream_events(info)
+                launches = 1
             else:
-                infos = []
-                for xc, xd in chunks:
-                    state, info = streaming.stream_update(
-                        self.cp, self.prior, state, xc, xd,
-                        sweeps=sweeps, tol=tol, backend=self.backend,
-                        chunk=self.chunk)
-                    infos.append(info)
-                done = jnp.stack([i["sweeps"] for i in infos])
-                drifted = jnp.stack([i["drifted"] for i in infos])
-                elbo = info["elbo"]
-        # one scoring pass per batch before its sweeps
-        self.last_update = UpdateCounters(sweeps=done, passes=done + 1,
-                                          drifted=drifted,
-                                          instances=state.n_seen)
+                state = streaming.stream_init(self._chained_prior,
+                                              self.posterior)
+                if equal:
+                    # windowed replay keeps the stack host-resident (numpy)
+                    state, info = streaming.stream_fit(
+                        self.cp, self.prior, state, np.stack(xcs),
+                        np.stack(xds), window=window, **kw)
+                    launches = -(-len(chunks) // window)
+                else:
+                    infos = []
+                    for xc, xd in chunks:
+                        state, info = streaming.stream_update(
+                            self.cp, self.prior, state, xc, xd, **kw)
+                        infos.append(info)
+                    info = {k: jnp.stack([i[k] for i in infos])
+                            for k in ("sweeps", "drifted", "elbo")}
+                    launches = len(chunks)
+                # one scoring pass per batch before its sweeps
+                post, passes, n = state.post, info["sweeps"] + 1, state.n_seen
+                elbo = info["elbo"][-1]
+        self.last_update = UpdateCounters(
+            sweeps=info["sweeps"], passes=passes, drifted=info["drifted"],
+            instances=n, launches=launches)
         with obs.span("update_model.wait"):
-            e = float(elbo)
-            self.n_seen += int(state.n_seen)
-        self.posterior = state.post
-        self._chained_prior = state.post
-        return e
+            e, n = jax.device_get((elbo, n))
+            self.n_seen += int(n)
+        self.posterior = post
+        self._chained_prior = post
+        return float(e)
 
     # -- queries -----------------------------------------------------------------
 

@@ -125,6 +125,7 @@ def test_update_model_stream_window_matches_full_scan():
     e = m.update_model(DataStream.concat(parts), sweeps=8)
     mw = GaussianMixture(full.attributes, n_states=2, seed=0)
     ew = mw.update_model(DataStream.concat(parts), sweeps=8, stream_window=2)
+    assert m.last_update.launches == 1 and mw.last_update.launches == 2
     np.testing.assert_allclose(np.asarray(m.posterior.reg.m),
                                np.asarray(mw.posterior.reg.m),
                                rtol=1e-5, atol=1e-5)
@@ -145,6 +146,7 @@ def test_update_model_ragged_stream_falls_back_to_per_batch():
     e = m.update_model(multi, sweeps=8)
     assert np.isfinite(e)
     assert m.n_seen == 900
+    assert m.last_update.launches == 2            # one vmp_fit a chunk
 
 
 def test_update_model_single_chunk_stream_keeps_batch_path():
@@ -156,3 +158,103 @@ def test_update_model_single_chunk_stream_keeps_batch_path():
     e = m.update_model(s, sweeps=30)
     assert np.isfinite(e)
     assert m.n_seen == 600
+
+
+# -- one streaming update_model call, one device program ----------------------
+
+
+def _mixed_chunks(n_chunks=8, rows=128, switch=4, seed=21):
+    """Chunks of a mixed plate (3 Gaussian and 2 discrete leaves of
+    cardinality 3, 2 latent classes) whose concept switches at chunk
+    ``switch``: the means move by 6 and the discrete tables are redrawn."""
+    from repro.data.stream import Attribute, FINITE, REAL
+
+    rng = np.random.default_rng(seed)
+    attrs = ([Attribute(f"G{i}", REAL) for i in range(3)]
+             + [Attribute(f"D{i}", FINITE, 3) for i in range(2)])
+    means = rng.uniform(-3, 3, (2, 3))
+    tables = [rng.dirichlet(np.full(3, 0.5), (2, 2)) for _ in range(2)]
+    chunks = []
+    for t in range(n_chunks):
+        c = int(t >= switch)
+        z = rng.integers(0, 2, rows)
+        xc = means[z] + 6.0 * c + 0.8 * rng.standard_normal((rows, 3))
+        u = rng.random((rows, 2, 1))
+        xd = (u > np.cumsum(tables[c][z], -1)).sum(-1)
+        chunks.append((xc.astype(np.float32), xd.astype(np.int32)))
+    return attrs, chunks
+
+
+def _stream_of(attrs, chunks):
+    from repro.data.stream import DataStream
+
+    return DataStream(attrs, lambda: iter(chunks),
+                      n_instances=sum(len(xc) for xc, _ in chunks))
+
+
+def test_stream_update_model_runs_no_eager_op():
+    """After warm-up, a streaming update_model call on equal chunks of a
+    mixed plate dispatches no eager primitive: stack, state set-up and
+    read-out all run inside its one program."""
+    import sys
+
+    import jax._src.dispatch as dispatch
+    from repro.pgm_models.static import NaiveBayes
+
+    attrs, chunks = _mixed_chunks()
+    model = NaiveBayes(attrs, n_states=2, seed=0)
+    for _ in range(2):                            # compile, then warm
+        model.update_model(_stream_of(attrs, chunks), sweeps=10, tol=1e-5)
+    code = dispatch.apply_primitive.__code__
+    prims = []
+
+    def hook(frame, event, arg):
+        if event == "call" and frame.f_code is code:
+            prims.append(frame.f_locals.get("prim"))
+
+    sys.setprofile(hook)
+    try:
+        e = model.update_model(_stream_of(attrs, chunks), sweeps=10,
+                               tol=1e-5)
+    finally:
+        sys.setprofile(None)
+    assert prims == []
+    assert model.last_update.launches == 1
+    assert np.isfinite(e) and model.n_seen == 3 * 8 * 128
+    assert np.asarray(model.last_update.sweeps).shape == (8,)
+
+
+def test_stream_call_matches_stream_fit():
+    """The fused update_model program equals stream_fit from stream_init
+    on the same chunks, across a concept switch that fires the drift
+    gate."""
+    from repro.pgm_models.static import NaiveBayes
+
+    attrs, chunks = _mixed_chunks()
+    model = NaiveBayes(attrs, n_states=2, seed=0)
+    xcs = tuple(jnp.asarray(xc) for xc, _ in chunks)
+    xds = tuple(jnp.asarray(xd) for _, xd in chunks)
+    kw = dict(sweeps=20, tol=1e-5, drift_threshold=3.0,
+              backend=model.backend, chunk=model.chunk)
+    post, info, passes, elbo, n = streaming._stream_call(
+        model.cp, model.prior, model._chained_prior, model.posterior,
+        xcs, xds, **kw)
+    state, ref = streaming.stream_fit(
+        model.cp, model.prior,
+        streaming.stream_init(model._chained_prior, model.posterior),
+        jnp.stack(xcs), jnp.stack(xds), **kw)
+    drifted = np.asarray(ref["drifted"])
+    assert drifted[4:].any() and not drifted[:4].any()
+    for k in ("sweeps", "drifted", "quarantined"):
+        np.testing.assert_array_equal(np.asarray(info[k]),
+                                      np.asarray(ref[k]), err_msg=k)
+    np.testing.assert_array_equal(np.asarray(passes),
+                                  np.asarray(ref["sweeps"]) + 1)
+    for a, b in zip(jax.tree_util.tree_leaves(post),
+                    jax.tree_util.tree_leaves(state.post)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(info["elbo"]),
+                               np.asarray(ref["elbo"]), rtol=1e-6)
+    np.testing.assert_allclose(float(elbo), float(ref["elbo"][-1]),
+                               rtol=1e-6)
+    np.testing.assert_allclose(float(n), float(state.n_seen), rtol=1e-6)
