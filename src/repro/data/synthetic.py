@@ -225,7 +225,7 @@ def random_discrete_bn(n_vars: int, card: int = 3, max_parents: int = 2,
     dependence — random Dirichlet tables routinely produce near-
     independent edges no score can recover.  Returns the
     ``BayesianNetwork`` (sample it with :func:`bn_stream`); ground truth
-    for ``learn_structure`` tests and the BENCH_structure driver.
+    for the ``learn_structure`` tests.
     """
     import jax.numpy as jnp
 

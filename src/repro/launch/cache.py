@@ -1,6 +1,6 @@
 """JAX's persistent compilation cache for the entry points.
 
-Entry points (``chip_smoke.py``, ``launch/*``, ``benchmarks/run.py``) call
+Entry points (``chip_smoke.py``, ``launch/*``, ``bench/run.py``) call
 :func:`use_compile_cache` before they compile anything; importing a module
 of this package never touches it.
 """

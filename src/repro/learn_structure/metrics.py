@@ -1,4 +1,4 @@
-"""Structure-recovery metrics shared by tests, benchmarks and CI smokes."""
+"""Structure-recovery metrics: a learned structure's edges against the truth."""
 
 from __future__ import annotations
 
@@ -23,8 +23,8 @@ def undirected_edges(structure: EdgeSource) -> Set[frozenset]:
 
 def skeleton_f1(true_structure: EdgeSource, got_structure: EdgeSource
                 ) -> float:
-    """F1 between undirected skeletons — the recovery metric gated by
-    ``validate_bench_structure`` and asserted in the tier-1 tests."""
+    """F1 between undirected skeletons — the recovery metric the
+    structure-learning tests assert."""
     t, g = undirected_edges(true_structure), undirected_edges(got_structure)
     if not t and not g:
         return 1.0          # an edgeless graph, exactly recovered

@@ -3,10 +3,8 @@
 Layering rule: this package (and everything imported here) is jax-free,
 so ``repro.obs`` can be imported before jax is configured —
 ``launch/dryrun.py`` must set ``XLA_FLAGS`` before the first jax import.
-The two jax-adjacent pieces are opt-in imports: ``repro.obs.metrics``
-holds the pytree definitions (itself jax-free; the arrays come from the
-caller) and ``repro.obs.profile`` imports jax lazily inside the context
-manager.
+The one jax-adjacent piece, ``repro.obs.metrics``, holds the pytree
+definitions (itself jax-free; the arrays come from the caller).
 
 Quick start::
 
@@ -19,8 +17,8 @@ Quick start::
     obs.emit("metric", name="elbo", value=-1.23)
 
 Spans are ``jax.profiler`` annotations at every level: under a profiler
-trace (``repro.obs.profile``) they sit on the host plane beside the
-device ops, on the same clock.
+trace (``jax.profiler.start_trace``) they sit on the host plane beside
+the device ops, on the same clock.
 
 See ``obs/sink.py`` for the event schema and README "Observability".
 """
@@ -28,11 +26,10 @@ See ``obs/sink.py`` for the event schema and README "Observability".
 from repro.obs.agg import (REGISTRY, MetricsRegistry, merge_snapshots,
                            quantile_from_snapshot)
 from repro.obs.sink import (BASIC, EVENT_SCHEMA, OFF, TRACE, configure,
-                            emit, emit_stream_events, enabled, estimate,
-                            level, log, register, registered,
+                            emit, emit_stream_events, enabled, level, log,
                             validate_obs_events)
 from repro.obs.trace import current_span, span
-from repro.obs.export import default_prometheus_text, prometheus_text
+from repro.obs.export import prometheus_text
 from repro.obs.health import HealthTracker
 from repro.obs.metrics import (DvmpMetrics, LocalStepMetrics,
                                StreamBatchMetrics, TemporalFitMetrics,
@@ -43,11 +40,10 @@ __all__ = [
     "configure", "enabled", "level",
     "emit", "log", "span", "current_span",
     "emit_stream_events",
-    "register", "registered", "estimate",
     "validate_obs_events",
     "REGISTRY", "MetricsRegistry", "merge_snapshots",
     "quantile_from_snapshot",
-    "prometheus_text", "default_prometheus_text",
+    "prometheus_text",
     "HealthTracker",
     "StreamBatchMetrics", "TemporalFitMetrics", "LocalStepMetrics",
     "DvmpMetrics", "UpdateCounters",

@@ -15,8 +15,6 @@ from __future__ import annotations
 
 from typing import Any, Dict, List
 
-from repro.obs import agg
-
 
 def _sanitize_name(name: str) -> str:
     out = [c if (c.isalnum() or c in "_:") else "_" for c in name]
@@ -87,7 +85,3 @@ def prometheus_text(snapshot: Dict[str, Any]) -> str:
         lines.append(f"{name}_count{_labels_text(e['labels'])} {e['count']}")
     return "\n".join(lines) + "\n"
 
-
-def default_prometheus_text() -> str:
-    """Prometheus exposition of the process-wide default registry."""
-    return prometheus_text(agg.REGISTRY.snapshot())

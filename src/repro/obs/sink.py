@@ -1,8 +1,8 @@
-"""JSONL telemetry sink, event schema, and the obs registry.
+"""JSONL telemetry sink and event schema.
 
 The measurement substrate every serving/perf PR reads from (ROADMAP:
 "production serving tier ... p50/p99 latency" and "roofline gate" both
-need a counter source).  Three pieces:
+need a counter source).  Two pieces:
 
 * **level knob** — ``REPRO_OBS=off|basic|trace`` (default ``off``).
   ``off`` is a zero-overhead no-op: every ``emit`` call is a single
@@ -14,15 +14,8 @@ need a counter source).  Three pieces:
   ``REPRO_OBS_PATH`` (default ``obs_events.jsonl``).  Base fields on every
   line: ``ts`` (unix seconds), ``seq`` (monotone per-process), ``run``
   (process run id), ``event`` (type).  Event types and their required
-  fields are in :data:`EVENT_SCHEMA`; :func:`validate_obs_events` is the
-  CI gate over an emitted file.
-
-* **registry** — named estimator functions (:func:`register` /
-  :func:`estimate`).  ``benchmarks/run.py`` registers the trip-count-aware
-  HLO cost model (``benchmarks/hlo_analysis.py``) under ``"hlo_cost"`` so
-  BENCH_* config blocks stamp analytical FLOP/byte estimates next to the
-  measured inst/s, and each estimate is also recorded as a
-  ``bench_estimate`` event.
+  fields are in :data:`EVENT_SCHEMA`; :func:`validate_obs_events` checks
+  an emitted file against it.
 """
 
 from __future__ import annotations
@@ -85,8 +78,6 @@ EVENT_SCHEMA: Dict[str, Tuple[str, ...]] = {
     "serve_worker": ("worker", "action", "requeued"),
     # streaming-state snapshot written (resilience/checkpoint.py)
     "checkpoint": ("t", "path", "reason"),
-    # registry estimator output (e.g. analytical HLO FLOP/byte model)
-    "bench_estimate": ("name", "estimate"),
     # per-replica health score snapshot (serve/queue.py supervisor)
     "serve_health": ("worker", "score", "ewma_ms", "flushes", "errors"),
     # rolling SLO snapshot per (mode, schema) — exact-rank quantiles from
@@ -107,7 +98,6 @@ class _State:
         self.seq = 0
         self.fh: Optional[io.TextIOBase] = None
         self.lock = threading.Lock()
-        self.registry: Dict[str, Any] = {}
 
 
 _STATE = _State()
@@ -224,32 +214,7 @@ def emit_stream_events(info: Dict[str, Any]) -> None:
 
 
 # ---------------------------------------------------------------------------
-# registry — named estimators (analytical cost models, ...)
-# ---------------------------------------------------------------------------
-
-
-def register(name: str, fn: Any) -> None:
-    """Register a named estimator callable in the obs registry."""
-    _STATE.registry[name] = fn
-
-
-def registered(name: str) -> bool:
-    return name in _STATE.registry
-
-
-def estimate(name: str, *args: Any, **kw: Any) -> Any:
-    """Run a registered estimator; record its output as a
-    ``bench_estimate`` event when obs is enabled.  Raises ``KeyError`` for
-    an unregistered name."""
-    fn = _STATE.registry[name]
-    out = fn(*args, **kw)
-    if _STATE.level >= BASIC:
-        emit("bench_estimate", name=name, estimate=out)
-    return out
-
-
-# ---------------------------------------------------------------------------
-# validation — the CI gate over an emitted JSONL file
+# validation of an emitted JSONL file
 # ---------------------------------------------------------------------------
 
 

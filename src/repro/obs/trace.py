@@ -1,10 +1,9 @@
 """Host-side spans on the profiler's clock.
 
 Every :func:`span` is a ``jax.profiler.TraceAnnotation`` under its bare
-name: while a profiler trace records (``obs.profile``, or any
-``jax.profiler.start_trace``) the span lands on the trace's host plane,
-on the same clock as the device ops, nested in the spans open around it
-on its thread.  At TRACE level the span is also timed and emitted as a
+name: while a profiler trace records (any ``jax.profiler.start_trace``)
+the span lands on the trace's host plane, on the same clock as the device
+ops, nested in the spans open around it on its thread.  At TRACE level the span is also timed and emitted as a
 JSONL ``span`` event with ``parent_id`` nesting.
 
 Spans measure HOST latency (queueing, trace/compile, dispatch+wait).

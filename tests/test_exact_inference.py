@@ -65,7 +65,7 @@ def test_jt_matches_brute_force(seed):
                 continue
             got = np.asarray(eng.posterior_discrete(v))
             exp = np.asarray(brute_posterior(bn, v, evidence))
-            np.testing.assert_allclose(got, exp, atol=1e-5)
+            np.testing.assert_allclose(got, exp, atol=1e-6)
 
 
 def test_jt_log_evidence_matches_brute_force():

@@ -70,7 +70,8 @@ def test_local_step_parity_latent_dim(backend, L, latent_card):
     ref_stats, ref_r = vmp.local_step(cp, post, xc, xd, mask)
     stats, r = vmp.local_step(cp, post, xc, xd, mask,
                               backend=backend, chunk=128)
-    _assert_stats_close(ref_stats, stats, f"{backend}/L{L}")
+    # within 1e-4 (1 + |ref|) on every statistic
+    _assert_stats_close(ref_stats, stats, f"{backend}/L{L}", atol=1e-4)
     np.testing.assert_allclose(np.asarray(ref_r), np.asarray(r), atol=1e-5)
 
 

@@ -21,8 +21,8 @@ from repro.data.stream import DataStream
 @contextlib.contextmanager
 def _obs_to(tmp_path, level="trace"):
     """Route obs events to a temp JSONL file at ``level``; restore the
-    previous config on exit (the CI leg runs pytest under REPRO_OBS=trace,
-    so tests must not assume the ambient level)."""
+    previous config on exit (tests must not assume the ambient level that
+    ``REPRO_OBS`` may set)."""
     path = str(tmp_path / "events.jsonl")
     prev = obs.configure(level=level, path=path, reset_counters=True)
     try:
@@ -304,6 +304,76 @@ def test_dvmp_fit_with_metrics_single_device_mesh():
     # the metric-free program is untouched (separate cache key)
     np.testing.assert_allclose(np.asarray(ref.post.reg.m),
                                np.asarray(st.post.reg.m), atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# end-to-end legs: the event types only a whole workload emits
+# ---------------------------------------------------------------------------
+
+
+def _temporal_leg():
+    """A fused dynamic-HMM fit."""
+    from repro.pgm_models import HiddenMarkovModel
+
+    batches, attrs, _ = syn.hmm_stream(n_batches=1, s=16, t=10, states=2,
+                                       f=2, shift=8.0, seed=0)
+    m = HiddenMarkovModel(attrs, n_states=2, seed=0)
+    assert np.isfinite(m.update_model(batches[0], sweeps=3))
+
+
+def _serve_leg():
+    """Timeout-triggered micro-batch flushes around a hot model swap."""
+    from repro.serve.queue import AsyncPGMServer
+
+    bn = syn.random_discrete_bn(5, card=2, max_parents=2, seed=0)
+    bn2 = syn.random_discrete_bn(5, card=2, max_parents=2, seed=1)
+    names = [v.name for v in bn.order]
+    with AsyncPGMServer(bn, mode="exact", max_batch=64, max_delay_ms=20,
+                        default_deadline_ms=60_000) as srv:
+        for swap_to in (bn2, None):
+            tickets = [srv.submit(names[-1], {names[0]: float(k % 2)})
+                       for k in range(3)]
+            for t in tickets:
+                assert np.isfinite(np.asarray(t.result(timeout=120))).all()
+            if swap_to is not None:
+                info = srv.swap_model(swap_to)
+                assert info["new_version"] == 1 and info["warmed_plans"] >= 1
+    assert srv.stats()["pending"] == 0
+
+
+def _shed_leg():
+    """A submit over the bounded queue's capacity is shed."""
+    from repro.resilience import ShedError
+    from repro.serve.queue import AsyncPGMServer
+
+    bn = syn.random_discrete_bn(5, card=2, max_parents=2, seed=0)
+    names = [v.name for v in bn.order]
+    with AsyncPGMServer(bn, mode="exact", max_batch=16, max_delay_ms=10_000,
+                        default_deadline_ms=60_000, max_queue=2) as srv:
+        kept = [srv.submit(names[-1], {names[0]: float(k % 2)})
+                for k in range(2)]
+        with pytest.raises(ShedError):
+            srv.submit(names[-1], {names[0]: 0.0}).result()
+    assert all(t.error is None for t in kept)
+
+
+# leg -> (workload, the event types it must emit that no narrower test
+# asserts)
+_LEGS = {
+    "temporal": (_temporal_leg, ("temporal_fit",)),
+    "serve": (_serve_leg, ("serve_deadline", "serve_swap")),
+    "chaos": (_shed_leg, ("serve_shed",)),
+}
+
+
+@pytest.mark.parametrize("leg", sorted(_LEGS))
+def test_obs_leg_emits_its_events(tmp_path, leg):
+    run, need = _LEGS[leg]
+    with _obs_to(tmp_path) as path:
+        run()
+        counts = obs.validate_obs_events(path)
+    missing = [ev for ev in need if not counts.get(ev)]
+    assert not missing, (missing, counts)
 
 
 # ---------------------------------------------------------------------------

@@ -306,28 +306,35 @@ def test_fit_cpds_recovers_tables():
             atol=0.05)
 
 
-def test_learned_bn_serves_exact_queries():
+@pytest.mark.parametrize("n_vars,seed,n_rows,evidence", [
+    (5, 0, 12_000, [{"D3": 1, "D4": 2}]),
+    # four queries of one evidence schema, answered as one bucket
+    (6, 3, 4_000, [{"D2": k % 3, "D3": (k + 1) % 3} for k in range(4)]),
+])
+def test_learned_bn_serves_exact_queries(n_vars, seed, n_rows, evidence):
     """The learned network drops into infer_exact / PGMQueryEngine and its
     answers match the generator's on the same junction tree."""
     from repro.infer_exact import JunctionTreeEngine
     from repro.serve.engine import PGMQueryEngine
 
-    bn = syn.random_discrete_bn(5, card=3, seed=0, tree=True)
-    stream = syn.bn_stream(bn, 12_000, seed=1)
-    _, learned = chow_liu(stream, stream.attributes)
+    bn = syn.random_discrete_bn(n_vars, card=3, seed=seed, tree=True)
+    stream = syn.bn_stream(bn, n_rows, seed=seed + 1)
+    edges, learned = chow_liu(stream, stream.attributes)
+    assert undirected_edges(edges) == undirected_edges(bn)
 
     eng = PGMQueryEngine(learned, mode="exact")
-    q = eng.submit("D0", {"D3": 1, "D4": 2})
+    qs = [eng.submit("D0", ev) for ev in evidence]
     eng.flush()
-    assert q.done and q.result.shape == (3,)
-    np.testing.assert_allclose(q.result.sum(), 1.0, atol=1e-5)
+    for q, ev in zip(qs, evidence):
+        assert q.done and q.result.shape == (3,)
+        np.testing.assert_allclose(q.result.sum(), 1.0, atol=1e-5)
 
-    ref = JunctionTreeEngine(bn)
-    ref.set_evidence({"D3": 1, "D4": 2})
-    ref.run_inference()
-    exact = np.asarray(ref.posterior_discrete(
-        bn.dag.variables.by_name("D0")))
-    np.testing.assert_allclose(q.result, exact, atol=0.06)
+        ref = JunctionTreeEngine(bn)
+        ref.set_evidence(ev)
+        ref.run_inference()
+        exact = np.asarray(ref.posterior_discrete(
+            bn.dag.variables.by_name("D0")))
+        np.testing.assert_allclose(q.result, exact, atol=0.06)
 
 
 # ---------------------------------------------------------------------------

@@ -155,6 +155,22 @@ def test_fhmm_fused_unfused_parity():
                                atol=1e-3)
 
 
+def test_fhmm_backend_parity():
+    """The Pallas suff-stats backend (interpret mode on the CPU) fits the
+    same factorial HMM as the einsum backend from one seed."""
+    stream = syn.hmm_sequences(s=16, t=8, states=3, f=2, seed=0)[0]
+    fits = {}
+    for backend in ("einsum", "pallas"):
+        m = FactorialHMMModel(stream.attributes, n_chains=2, n_states=2,
+                              seed=0)
+        elbo = m.update_model(stream, sweeps=2, tol=0.0, backend=backend)
+        fits[backend] = (elbo, np.asarray(m.means))
+    np.testing.assert_allclose(fits["pallas"][0], fits["einsum"][0],
+                               rtol=1e-4)
+    np.testing.assert_allclose(fits["pallas"][1], fits["einsum"][1],
+                               atol=1e-4)
+
+
 def test_kalman_fused_unfused_parity():
     stream = syn.lds_sequences(s=12, t=15, dim_h=2, f=3, seed=6)[0]
     m1 = KalmanFilter(stream.attributes, n_hidden=2, seed=0)
