@@ -17,17 +17,19 @@ Implements:
 All of this works identically on one device or on the d-VMP mesh (pass
 ``mesh=``) — the paper's headline "same code multi-core or distributed".
 
-Two drivers share one step body (:func:`_stream_step`):
+Three drivers share one step body (:func:`_stream_step`):
 
 * :func:`stream_update` — one host call per arriving batch (the online API);
 * :func:`stream_fit` — T stacked batches in ONE jitted ``lax.scan`` with the
   drift test and prior tempering inside the scan body and the
   ``StreamState`` buffers donated, so the whole stream replay is a single
-  resident device program (no per-batch host round-trip or dispatch).
+  resident device program (no per-batch host round-trip or dispatch);
+* :class:`StreamPipeline` — batches that arrive one at a time, one device
+  program per batch, each launched as soon as its batch is on the device,
+  with the state carried (donated) on the device from one to the next.
 
-``Model.update_model`` on equal-shape chunks calls :func:`_stream_call`,
-which runs the :func:`stream_fit` scan inside one program together with
-the stack, the state set-up and the read-out around it.
+``Model.update_model`` on a multi-chunk stream drives a
+:class:`StreamPipeline`: the copy of chunk t+1 overlaps the fit of chunk t.
 """
 
 from __future__ import annotations
@@ -160,8 +162,9 @@ def _stream_step(
 ) -> Tuple[StreamState, Dict[str, jnp.ndarray]]:
     """score -> (maybe) drift -> Bayesian update, as pure traced ops.
 
-    THE step body, shared by the per-batch :func:`stream_update` API and
-    the :func:`stream_fit` scan — both drivers run exactly this math.
+    THE step body, shared by the per-batch :func:`stream_update` API, the
+    :func:`stream_fit` scan and the :class:`StreamPipeline` programs — all
+    drivers run exactly this math.
     ``fit_fn(prior, post) -> (post, elbo, sweeps)`` supplies the inner VMP
     fit (jitted ``vmp_fit``, traced ``fit_loop`` or d-VMP sweeps).
 
@@ -297,37 +300,117 @@ def _stream_fit_scan(cp, base_prior, state, xcs, xds, masks, *, sweeps, tol,
     return jax.lax.scan(step, state, (xcs, xds, masks))
 
 
-@partial(
-    jax.jit,
-    static_argnums=(0,),
-    static_argnames=("sweeps", "tol", "drift_threshold", "forget",
-                     "backend", "chunk"),
-)
-def _stream_call(cp, base_prior, chained, post, xcs, xds, *, sweeps, tol,
-                 backend, chunk, drift_threshold=5.0, forget=0.3):
-    """One streaming ``Model.update_model`` call as ONE device program.
+_FIT_KW = ("sweeps", "tol", "drift_threshold", "forget", "backend", "chunk")
 
-    ``xcs``/``xds`` are tuples of T equal-shape per-chunk arrays, each
-    already copied to the device on its own (so the staging of chunk t+1
-    overlaps the copy of chunk t); the stack, the all-real masks and the
-    fresh :class:`StreamState` (from ``chained`` and ``post``, no drift
-    history) are made in-graph, the batches replay through the
-    :func:`stream_fit` scan, and what the caller reads is computed in the
-    same program.  Nothing is donated: the state is born inside.
 
-    Returns ``(post, info, passes, elbo, n_seen)``: the posterior after the
-    last batch, the per-batch info columns of :func:`stream_fit`, the
-    local-step passes per batch (its sweeps plus the scoring pass), the
-    last batch's ELBO and the instances absorbed.
+class _Run(NamedTuple):
+    """What one :class:`StreamPipeline` program hands the next."""
+
+    state: StreamState
+    cols: Dict[str, jnp.ndarray]   # per-batch info columns, [capacity]
+    t: jnp.ndarray                 # batches absorbed so far
+
+
+def _pipeline_step(cp, base_prior, state, cols, t, xc, xd, capacity, kw):
+    """One batch through the :func:`stream_fit` scan, as a scan of one;
+    its info (and ``passes``, the local-step passes: its sweeps plus the
+    scoring pass) written at index ``t`` of the columns, which are made
+    here when ``cols`` is None."""
+    state, info = _stream_fit_scan(cp, base_prior, state, xc[None],
+                                   xd[None], jnp.ones((1, xc.shape[0])),
+                                   **kw)
+    info = {k: v[0] for k, v in info.items()}
+    info["passes"] = info["sweeps"] + 1
+    if cols is None:
+        cols = {k: jnp.zeros((capacity,), v.dtype) for k, v in info.items()}
+    cols = {k: cols[k].at[t].set(v) for k, v in info.items()}
+    return _Run(state, cols, t + 1), info["elbo"]
+
+
+@partial(jax.jit, static_argnums=(0,),
+         static_argnames=_FIT_KW + ("capacity",))
+def _pipeline_first(cp, base_prior, chained, post, xc, xd, *, capacity,
+                    **kw):
+    """The first batch's program: the state is born here, from
+    ``chained`` and ``post`` with no drift history; nothing is donated."""
+    return _pipeline_step(cp, base_prior, stream_init(chained, post), None,
+                          jnp.asarray(0), xc, xd, capacity, kw)
+
+
+@partial(jax.jit, static_argnums=(0,), static_argnames=_FIT_KW,
+         donate_argnums=(2,))
+def _pipeline_next(cp, base_prior, run, xc, xd, **kw):
+    """Every later batch's program, on the previous one's run, donated."""
+    return _pipeline_step(cp, base_prior, run.state, run.cols, run.t, xc,
+                          xd, None, kw)
+
+
+@partial(jax.jit, static_argnums=(1,))
+def _resize(cols, n):
+    """The columns cut, or zero-padded, to length ``n``."""
+    return {k: jnp.zeros((n,), c.dtype).at[:min(n, len(c))].set(c[:n])
+            for k, c in cols.items()}
+
+
+class StreamPipeline:
+    """One streaming update over batches that arrive one at a time: the
+    :func:`stream_fit` replay without the stack.
+
+    Each :meth:`step` launches one device program on a batch already on
+    the device and returns without waiting for it, so the caller stages
+    the next batch while the device fits this one.  The
+    :class:`StreamState` stays on the device from program to program: the
+    first builds it from ``chained`` and ``post`` (:func:`stream_init`, no
+    drift history), each later one takes the last one's, donated.  Every
+    value read afterwards comes out of the programs: the per-batch info
+    columns, written at index t in-graph, the last batch's ELBO and the
+    instances absorbed.
+
+    ``capacity`` is the number of batches expected, the columns' length.
+    A stream that outruns it doubles them, and :meth:`finish` cuts them to
+    the batches seen; one small program each, launched only on a wrong
+    guess.  ``launches`` counts the fit programs, one a batch.
     """
-    xcs, xds = jnp.stack(xcs), jnp.stack(xds)
-    state, info = _stream_fit_scan(
-        cp, base_prior, stream_init(chained, post), xcs, xds,
-        jnp.ones(xcs.shape[:2]), sweeps=sweeps, tol=tol,
-        drift_threshold=drift_threshold, forget=forget, backend=backend,
-        chunk=chunk)
-    return (state.post, info, info["sweeps"] + 1, info["elbo"][-1],
-            state.n_seen)
+
+    def __init__(self, cp: CompiledPlate, base_prior: PlateParams,
+                 chained: PlateParams, post: PlateParams, *, capacity: int,
+                 sweeps: int = 20, tol: float = 1e-4,
+                 drift_threshold: float = 5.0, forget: float = 0.3,
+                 backend: str = "einsum", chunk: Optional[int] = None):
+        self._fixed = (cp, base_prior)
+        self._start = (chained, post)
+        self._kw = dict(sweeps=sweeps, tol=tol,
+                        drift_threshold=drift_threshold, forget=forget,
+                        backend=backend, chunk=chunk)
+        self._capacity = max(int(capacity), 1)
+        self._run: Optional[_Run] = None
+        self._elbo = None
+        self.launches = 0
+
+    def step(self, xc: jnp.ndarray, xd: jnp.ndarray) -> None:
+        """Launch the fit of one batch (``xc`` [B, F], ``xd`` [B, Fd])."""
+        if self._run is None:
+            self._run, self._elbo = _pipeline_first(
+                *self._fixed, *self._start, xc, xd,
+                capacity=self._capacity, **self._kw)
+        else:
+            if self.launches == self._capacity:
+                self._capacity *= 2
+                self._run = self._run._replace(
+                    cols=_resize(self._run.cols, self._capacity))
+            self._run, self._elbo = _pipeline_next(
+                *self._fixed, self._run, xc, xd, **self._kw)
+        self.launches += 1
+
+    def finish(self):
+        """``(state, info, elbo)`` after the last batch: the final
+        :class:`StreamState`, the per-batch columns of :func:`stream_fit`
+        plus ``passes``, each of leading dim T, and the last batch's ELBO,
+        all on the device."""
+        cols = self._run.cols
+        if self.launches != self._capacity:
+            cols = _resize(cols, self.launches)
+        return self._run.state, cols, self._elbo
 
 
 def stream_fit(
@@ -361,6 +444,10 @@ def stream_fit(
     instead of T, with only O(w * B) of the stream resident on device.
     ``window=None`` keeps the whole stream in one scan (fastest, largest
     footprint).  The tail window may retrace once if ``T % w != 0``.
+    Batches that arrive one at a time, as ``Model.update_model``'s stream
+    chunks do, go through :class:`StreamPipeline` instead: the same step,
+    one program a batch, launched as soon as that batch is on the device
+    rather than once the whole stack is.
 
     Returns the final state and per-batch info arrays ``{"elbo", "score",
     "ph", "drifted", "n_eff", "rho", "sweeps", "quarantined"}`` each of

@@ -75,6 +75,6 @@ class UpdateCounters(NamedTuple):
                     # the path runs no drift test
     instances: Any  # instances absorbed by the call
     launches: int   # jitted fit programs the call launched, a host int:
-                    # 1 for one fit (fused stream call, vmp_fit, dvmp_fit),
-                    # one per window of a windowed replay, one vmp_fit per
-                    # ragged chunk (stream_update), 0 for the closed form
+                    # 1 for one fit (vmp_fit, dvmp_fit), one per chunk on
+                    # the stream path, one per window of a windowed
+                    # replay, 0 for the closed form

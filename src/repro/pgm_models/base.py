@@ -9,6 +9,7 @@ distributed d-VMP (``mesh=``) or streaming Bayesian updating (repeated calls
 
 from __future__ import annotations
 
+import itertools
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -22,6 +23,12 @@ from repro.core.dag import (BayesianNetwork, CLGCPD, DAG, MultinomialCPD,
                             PlateSpec, Variables)
 from repro.data.stream import Attribute, Batch, DataStream, FINITE, REAL
 from repro.obs.metrics import UpdateCounters
+
+
+def _to_device(chunk):
+    """One stream chunk ``(xc, xd)`` copied to the device."""
+    xc, xd = chunk
+    return jnp.asarray(xc, jnp.float32), jnp.asarray(xd)
 
 
 class Model:
@@ -86,26 +93,26 @@ class Model:
         posterior becomes the prior for the new data.  Returns the ELBO.
 
         A multi-batch ``DataStream`` (a source yielding several chunks)
-        routes through ``streaming``: equal-shape chunks are copied to the
-        device one by one and the call is ONE device program that stacks
-        them and replays them in the ``stream_fit`` ``lax.scan`` (drift
-        test + tempering resident on device); ragged chunk shapes fall
-        back to the per-batch ``stream_update`` loop.  Single-chunk
-        streams, raw arrays and ``Batch``es keep the one-shot VMP fit
-        below.  The stacked replay is whole-stream-resident by default
-        (the scan consumes [T, B, F] on device); ``stream_window=w`` keeps
-        the stack on the host and replays device-sliced windows of w
-        batches instead — bounded device memory for streams larger than
-        memory.
+        routes through ``streaming``: each chunk is copied to the device
+        and its fit launched at once (``streaming.StreamPipeline``: the
+        ``stream_fit`` step with the drift test and tempering, the state
+        carried on the device from chunk to chunk), so the copy of chunk
+        t+1 overlaps the fit of chunk t; ragged chunk shapes take the same
+        path.  Single-chunk streams, raw arrays and ``Batch``es keep the
+        one-shot VMP fit below.  ``stream_window=w`` instead stacks
+        equal-shape chunks on the host and replays device-sliced windows
+        of w batches through ``stream_fit``.
 
         The call is an ``update_model`` span (``repro.obs``) whose children
         are ``update_model.ingest`` (host data to device arrays),
         ``update_model.dispatch`` (the call into the fit) and
         ``update_model.wait`` (reading the ELBO and the instance count,
-        which waits for the device; one transfer on the stream path).  The
-        fit's in-graph counters are kept in :attr:`last_update`
-        (:class:`~repro.obs.metrics.UpdateCounters`) as device arrays that
-        nothing in the call reads.
+        which waits for the device; one transfer on the stream path).  On
+        the stream path each chunk has its own ``.ingest`` and
+        ``.dispatch``, interleaved, and a last ``.ingest`` finds the end of
+        the stream.  The fit's in-graph counters are kept in
+        :attr:`last_update` (:class:`~repro.obs.metrics.UpdateCounters`) as
+        device arrays that nothing in the call reads.
         """
         with obs.span("update_model"):
             return self._update_model(data, sweeps, tol, mesh,
@@ -117,7 +124,8 @@ class Model:
             batch, chunks = self._ingest(data, mesh, data_axes)
         if batch is None:
             return self._update_model_stream(chunks, sweeps=sweeps, tol=tol,
-                                             window=window)
+                                             window=window,
+                                             expected=data.n_instances)
         prior = self._chained_prior
         with obs.span("update_model.dispatch"):
             r_fixed = self.supervised_r(batch)
@@ -154,74 +162,88 @@ class Model:
 
     def _ingest(self, data, mesh, data_axes):
         """Host data to device arrays: ``(batch, None)`` for the one-shot
-        fit, or ``(None, chunks)`` for the streaming path, each chunk's
-        ``(xc, xd)`` copied to the device on its own."""
+        fit, or ``(None, chunks)`` for the streaming path.  A stream is
+        read one chunk ahead, to tell a one-chunk stream: ``chunks``
+        iterates over the stream's ``(xc, xd)``, the first already on the
+        device, the second pulled from the source, the rest not yet."""
         if (mesh is None and isinstance(data, DataStream)
                 and type(self).supervised_r is Model.supervised_r):
-            chunks = [(jnp.asarray(xc, jnp.float32), jnp.asarray(xd))
-                      for xc, xd in data.chunks()]
-            if len(chunks) > 1:
-                return None, chunks
-            if chunks:
+            source = data.chunks()
+            first = next(source, None)
+            if first is not None:
+                first = _to_device(first)
+                second = next(source, None)
+                if second is not None:
+                    return None, itertools.chain([first, second], source)
                 # single chunk: reuse it instead of re-running the source
                 # (sources need not be restartable)
-                xc, xd = chunks[0]
+                xc, xd = first
                 data = Batch(xc, xd, jnp.ones(xc.shape[0], jnp.float32))
         return self._as_batch(
             data, None if mesh is None
             else NamedSharding(mesh, PartitionSpec(tuple(data_axes)))), None
 
     def _update_model_stream(self, chunks, *, sweeps: int, tol: float,
-                             window: Optional[int] = None) -> float:
-        """Streaming Bayesian updating over pre-chunked data (ROADMAP item:
-        ``stream_fit`` underneath ``update_model``).  Equal-shape chunks
-        run as one program (``streaming._stream_call``: stack, state
-        set-up, the ``stream_fit`` scan and the read-out); with ``window``
-        they are stacked on the host and replayed by ``stream_fit`` a
-        window at a time; ragged chunks run one ``stream_update`` each."""
+                             window: Optional[int] = None,
+                             expected: Optional[int] = None) -> float:
+        """Streaming Bayesian updating over a chunked stream (``stream_fit``
+        underneath ``update_model``).  Each chunk is copied to the device
+        and its step launched before the next chunk is pulled
+        (``streaming.StreamPipeline``), one launch a chunk; ``expected``
+        (instances the stream declares) sizes the per-batch columns.  With
+        ``window`` equal-shape chunks are stacked on the host and replayed
+        by ``stream_fit`` a window at a time."""
         from repro.core import streaming
 
         kw = dict(sweeps=sweeps, tol=tol, backend=self.backend,
                   chunk=self.chunk)
-        with obs.span("update_model.dispatch"):
-            xcs, xds = zip(*chunks)
-            equal = len({(xc.shape, xd.shape) for xc, xd in chunks}) == 1
-            if equal and window is None:
-                post, info, passes, elbo, n = streaming._stream_call(
-                    self.cp, self.prior, self._chained_prior, self.posterior,
-                    xcs, xds, **kw)
-                if obs.enabled():
-                    obs.emit_stream_events(info)
-                launches = 1
-            else:
-                state = streaming.stream_init(self._chained_prior,
-                                              self.posterior)
-                if equal:
-                    # windowed replay keeps the stack host-resident (numpy)
+        if window is not None:
+            with obs.span("update_model.ingest"):
+                chunks = list(chunks)
+                xcs = [np.asarray(xc, np.float32) for xc, _ in chunks]
+                xds = [np.asarray(xd) for _, xd in chunks]
+            if len({(xc.shape, xd.shape) for xc, xd in zip(xcs, xds)}) == 1:
+                with obs.span("update_model.dispatch"):
+                    state = streaming.stream_init(self._chained_prior,
+                                                  self.posterior)
                     state, info = streaming.stream_fit(
                         self.cp, self.prior, state, np.stack(xcs),
                         np.stack(xds), window=window, **kw)
-                    launches = -(-len(chunks) // window)
-                else:
-                    infos = []
-                    for xc, xd in chunks:
-                        state, info = streaming.stream_update(
-                            self.cp, self.prior, state, xc, xd, **kw)
-                        infos.append(info)
-                    info = {k: jnp.stack([i[k] for i in infos])
-                            for k in ("sweeps", "drifted", "elbo")}
-                    launches = len(chunks)
-                # one scoring pass per batch before its sweeps
-                post, passes, n = state.post, info["sweeps"] + 1, state.n_seen
-                elbo = info["elbo"][-1]
+                    # one scoring pass per batch before its sweeps
+                    info["passes"] = info["sweeps"] + 1
+                    elbo = info["elbo"][-1]
+                return self._stream_done(state, info, elbo,
+                                         -(-len(chunks) // window))
+            chunks = iter(chunks)
+        first = next(chunks)
+        rows = max(len(first[0]), 1)
+        pipe = streaming.StreamPipeline(
+            self.cp, self.prior, self._chained_prior, self.posterior,
+            capacity=max(2, -(-expected // rows) if expected else 2), **kw)
+        xc, xd = first
+        while True:
+            with obs.span("update_model.dispatch"):
+                pipe.step(xc, xd)
+            with obs.span("update_model.ingest"):
+                nxt = next(chunks, None)
+                if nxt is None:
+                    break
+                xc, xd = _to_device(nxt)
+        state, info, elbo = pipe.finish()
+        if obs.enabled():
+            obs.emit_stream_events(info)
+        return self._stream_done(state, info, elbo, pipe.launches)
+
+    def _stream_done(self, state, info, elbo, launches) -> float:
         self.last_update = UpdateCounters(
-            sweeps=info["sweeps"], passes=passes, drifted=info["drifted"],
-            instances=n, launches=launches)
+            sweeps=info["sweeps"], passes=info["passes"],
+            drifted=info["drifted"],
+            instances=state.n_seen, launches=launches)
         with obs.span("update_model.wait"):
-            e, n = jax.device_get((elbo, n))
+            e, n = jax.device_get((elbo, state.n_seen))
             self.n_seen += int(n)
-        self.posterior = post
-        self._chained_prior = post
+        self.posterior = state.post
+        self._chained_prior = state.post
         return float(e)
 
     # -- queries -----------------------------------------------------------------

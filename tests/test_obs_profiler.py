@@ -147,11 +147,15 @@ def test_update_model_spans(tmp_path, path):
     with _profiled(tmp_path) as events:
         model.update_model(data, sweeps=5)
     (root,) = _named(events, "update_model")
-    for child in UPDATE_CHILDREN:
-        (ev,) = _named(events, child)
-        assert _inside(ev, root) and ev[3] == root[3]
-    starts = [_named(events, c)[0][1] for c in UPDATE_CHILDREN]
-    assert starts == sorted(starts)               # ingest, dispatch, wait
+    kids = sorted((e for e in events if e[0] in UPDATE_CHILDREN),
+                  key=lambda e: e[1])
+    assert all(_inside(e, root) and e[3] == root[3] for e in kids)
+    # the stream path: an ingest and a dispatch per chunk, interleaved,
+    # then the ingest that finds the stream's end
+    expect = (list(UPDATE_CHILDREN[:2]) * (1 if path == "array" else 3)
+              + (["update_model.ingest"] if path == "stream" else [])
+              + ["update_model.wait"])
+    assert [e[0] for e in kids] == expect
 
 
 def test_last_update_matches_vmp_fit():

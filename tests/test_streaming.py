@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import streaming, svi, vmp
 from repro.core.dag import PlateSpec
@@ -125,7 +126,7 @@ def test_update_model_stream_window_matches_full_scan():
     e = m.update_model(DataStream.concat(parts), sweeps=8)
     mw = GaussianMixture(full.attributes, n_states=2, seed=0)
     ew = mw.update_model(DataStream.concat(parts), sweeps=8, stream_window=2)
-    assert m.last_update.launches == 1 and mw.last_update.launches == 2
+    assert m.last_update.launches == 4 and mw.last_update.launches == 2
     np.testing.assert_allclose(np.asarray(m.posterior.reg.m),
                                np.asarray(mw.posterior.reg.m),
                                rtol=1e-5, atol=1e-5)
@@ -146,7 +147,7 @@ def test_update_model_ragged_stream_falls_back_to_per_batch():
     e = m.update_model(multi, sweeps=8)
     assert np.isfinite(e)
     assert m.n_seen == 900
-    assert m.last_update.launches == 2            # one vmp_fit a chunk
+    assert m.last_update.launches == 2            # one step program a chunk
 
 
 def test_update_model_single_chunk_stream_keeps_batch_path():
@@ -160,7 +161,39 @@ def test_update_model_single_chunk_stream_keeps_batch_path():
     assert m.n_seen == 600
 
 
-# -- one streaming update_model call, one device program ----------------------
+def test_update_model_ragged_stream_matches_per_batch_loop():
+    """Ragged chunks (500 + 400 rows) take the pipelined path, one program
+    per chunk shape, and land where the per-batch stream_update loop
+    does."""
+    from repro.data.stream import DataStream
+    from repro.pgm_models import GaussianMixture
+
+    full, _, _ = gmm_stream(900, 2, 3, seed=14)
+    xc = np.asarray(full.collect().xc)
+    parts = [xc[:500], xc[500:]]
+    m = GaussianMixture(full.attributes, n_states=2, seed=0)
+    e = m.update_model(DataStream.concat(
+        [DataStream.from_arrays(full.attributes, p) for p in parts]),
+        sweeps=8)
+
+    ref = GaussianMixture(full.attributes, n_states=2, seed=0)
+    ss = streaming.stream_init(ref._chained_prior, ref.posterior)
+    sweeps = []
+    for p in parts:
+        ss, info = streaming.stream_update(
+            ref.cp, ref.prior, ss, jnp.asarray(p),
+            jnp.zeros((len(p), 0), jnp.int32), sweeps=8, tol=1e-5,
+            backend=ref.backend, chunk=ref.chunk)
+        sweeps.append(int(info["sweeps"]))
+    np.testing.assert_array_equal(np.asarray(m.last_update.sweeps), sweeps)
+    for a, b in zip(jax.tree_util.tree_leaves(m.posterior),
+                    jax.tree_util.tree_leaves(ss.post)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5)
+    np.testing.assert_allclose(e, float(info["elbo"]), rtol=1e-5)
+    assert m.n_seen == 900
+
+
+# -- one streaming update_model call, one program a chunk ----------------------
 
 
 def _mixed_chunks(n_chunks=8, rows=128, switch=4, seed=21):
@@ -185,17 +218,25 @@ def _mixed_chunks(n_chunks=8, rows=128, switch=4, seed=21):
     return attrs, chunks
 
 
-def _stream_of(attrs, chunks):
+def _stream_of(attrs, chunks, log=None):
+    """A stream over ``chunks``; ``log`` gets ``("pull", t)`` as the
+    source hands out chunk t."""
     from repro.data.stream import DataStream
 
-    return DataStream(attrs, lambda: iter(chunks),
+    def source():
+        for t, chunk in enumerate(chunks):
+            if log is not None:
+                log.append(("pull", t))
+            yield chunk
+
+    return DataStream(attrs, source,
                       n_instances=sum(len(xc) for xc, _ in chunks))
 
 
 def test_stream_update_model_runs_no_eager_op():
     """After warm-up, a streaming update_model call on equal chunks of a
-    mixed plate dispatches no eager primitive: stack, state set-up and
-    read-out all run inside its one program."""
+    mixed plate dispatches no eager primitive: state set-up, the per-batch
+    columns and the read-out all run inside its per-chunk programs."""
     import sys
 
     import jax._src.dispatch as dispatch
@@ -219,42 +260,77 @@ def test_stream_update_model_runs_no_eager_op():
     finally:
         sys.setprofile(None)
     assert prims == []
-    assert model.last_update.launches == 1
+    assert model.last_update.launches == 8
     assert np.isfinite(e) and model.n_seen == 3 * 8 * 128
     assert np.asarray(model.last_update.sweeps).shape == (8,)
 
 
-def test_stream_call_matches_stream_fit():
-    """The fused update_model program equals stream_fit from stream_init
+def test_stream_update_model_launches_each_step_before_next_pull(
+        monkeypatch):
+    """The call reads one chunk ahead only to tell a one-chunk stream;
+    from then on each chunk's step is launched before the next chunk is
+    pulled from the source, so the host stages chunk t+1 while the device
+    fits chunk t."""
+    from repro.pgm_models.static import NaiveBayes
+
+    attrs, chunks = _mixed_chunks(n_chunks=5)
+    log = []
+    step = streaming.StreamPipeline.step
+
+    def recorded(self, xc, xd):
+        log.append(("step", self.launches))
+        return step(self, xc, xd)
+
+    monkeypatch.setattr(streaming.StreamPipeline, "step", recorded)
+    model = NaiveBayes(attrs, n_states=2, seed=0)
+    model.update_model(_stream_of(attrs, chunks, log), sweeps=5, tol=1e-5)
+    assert log == [("pull", 0), ("pull", 1), ("step", 0), ("step", 1),
+                   ("pull", 2), ("step", 2), ("pull", 3), ("step", 3),
+                   ("pull", 4), ("step", 4)]
+    assert model.last_update.launches == 5
+
+
+@pytest.mark.parametrize("capacity", [8, 3, 20])
+def test_stream_call_matches_stream_fit(capacity):
+    """The pipelined per-chunk programs equal stream_fit from stream_init
     on the same chunks, across a concept switch that fires the drift
-    gate."""
+    gate; the columns are as long as the stream whether the expected
+    count was right (8), short (3: doubled on the way) or long (20).
+    The reference replays one batch a program (``window=1``) as the
+    pipeline does: a scan over all 8 compiles the step as a loop body and
+    rounds the tempered prior differently in the last bits."""
     from repro.pgm_models.static import NaiveBayes
 
     attrs, chunks = _mixed_chunks()
     model = NaiveBayes(attrs, n_states=2, seed=0)
-    xcs = tuple(jnp.asarray(xc) for xc, _ in chunks)
-    xds = tuple(jnp.asarray(xd) for _, xd in chunks)
+    xcs = [jnp.asarray(xc) for xc, _ in chunks]
+    xds = [jnp.asarray(xd) for _, xd in chunks]
     kw = dict(sweeps=20, tol=1e-5, drift_threshold=3.0,
               backend=model.backend, chunk=model.chunk)
-    post, info, passes, elbo, n = streaming._stream_call(
+    pipe = streaming.StreamPipeline(
         model.cp, model.prior, model._chained_prior, model.posterior,
-        xcs, xds, **kw)
+        capacity=capacity, **kw)
+    for xc, xd in zip(xcs, xds):
+        pipe.step(xc, xd)
+    out, info, elbo = pipe.finish()
+    assert pipe.launches == 8
     state, ref = streaming.stream_fit(
         model.cp, model.prior,
         streaming.stream_init(model._chained_prior, model.posterior),
-        jnp.stack(xcs), jnp.stack(xds), **kw)
+        jnp.stack(xcs), jnp.stack(xds), window=1, **kw)
     drifted = np.asarray(ref["drifted"])
     assert drifted[4:].any() and not drifted[:4].any()
     for k in ("sweeps", "drifted", "quarantined"):
         np.testing.assert_array_equal(np.asarray(info[k]),
                                       np.asarray(ref[k]), err_msg=k)
-    np.testing.assert_array_equal(np.asarray(passes),
+    np.testing.assert_array_equal(np.asarray(info["passes"]),
                                   np.asarray(ref["sweeps"]) + 1)
-    for a, b in zip(jax.tree_util.tree_leaves(post),
+    for a, b in zip(jax.tree_util.tree_leaves(out.post),
                     jax.tree_util.tree_leaves(state.post)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
     np.testing.assert_allclose(np.asarray(info["elbo"]),
                                np.asarray(ref["elbo"]), rtol=1e-6)
     np.testing.assert_allclose(float(elbo), float(ref["elbo"][-1]),
                                rtol=1e-6)
-    np.testing.assert_allclose(float(n), float(state.n_seen), rtol=1e-6)
+    np.testing.assert_allclose(float(out.n_seen), float(state.n_seen),
+                               rtol=1e-6)
